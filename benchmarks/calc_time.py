@@ -20,7 +20,7 @@ against the exact pre-PR unrolled arithmetic on the same prebuilt table;
 ``fig5_ladder_speedup_n4096`` is the ratio (acceptance: >= 2x).
 
 Device variants: ``fig5_asura_device_n*`` times the engine's zero-host-sync
-``place_nodes_device`` path (jnp reference kernels off-TPU, Pallas on TPU),
+``place_nodes_device`` path (the jnp reference kernels, compiled by XLA),
 ids resident on device, result blocked on device.
 """
 
@@ -86,8 +86,8 @@ def bench_asura_engine(n_nodes: int, batch: int):
 def bench_asura_device(n_nodes: int, batch: int):
     """Engine device path: ids resident on device, zero host syncs between
     calls (placement + tail + node gather fused on device).  backend="auto"
-    so the number tracks the shipped kernels: jnp reference off-TPU, Pallas
-    on TPU."""
+    so the number tracks what the engine ships: the jnp reference kernels
+    on every platform (on a TPU ``auto`` resolves to ``ref``)."""
     import jax.numpy as jnp
 
     cluster = make_uniform_cluster(n_nodes)

@@ -3,7 +3,7 @@
 All four algorithms -- ASURA, Consistent Hashing ("ch"), capacity-weighted
 Rendezvous Hashing ("wrh") and Random Slicing ("rs") -- run through the SAME
 ``PlacementEngine`` artifact interface at a COMMON scale, on the device-
-resident backends (jnp reference kernels off-TPU, Pallas on TPU), so the
+resident backends (the jnp reference kernels, compiled by XLA), so the
 comparison measures the algorithms, not the plumbing.  Paper-figure mapping:
 
   * ``h2h_calc_<alg>_n<N>``      -- Fig. 5: distribution-stage time per id

@@ -21,6 +21,8 @@ import os
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (
     actual_usage,
     calc_time,
@@ -101,6 +103,7 @@ def main(argv=None) -> int:
         "--out-dir", default=".", help="directory for the BENCH_*.json files"
     )
     args = ap.parse_args(argv)
+    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     os.makedirs(args.out_dir, exist_ok=True)
     picks = args.only.split(",") if args.only else None
     for name, mod in SUITES.items():

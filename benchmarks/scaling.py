@@ -22,6 +22,12 @@ physical core count, not the forced device count (a single-core runner
 measures ~1x -- the committed baselines record what the baseline machine
 saw, and the perf gate's calibration normalization absorbs machine
 differences).
+
+This is CPU-only by construction: the workers run with
+``JAX_PLATFORMS=cpu``, and a parent on any other platform refuses to start
+them (``require_cpu``) -- it holds the chip, and a child reaching for it
+would hang or measure the wrong device.  Scaling across real chips is
+one-process mesh work, not this worker sweep.
 """
 
 from __future__ import annotations
@@ -31,6 +37,8 @@ import os
 import subprocess
 import sys
 import time
+
+from repro.launch.placement_mesh import force_host_devices, require_cpu
 
 N_NODES = 128
 
@@ -61,6 +69,9 @@ def measure(quick: bool) -> dict[int, dict]:
     cached for the life of the benchmark process."""
     quick = bool(quick)
     if quick not in _CACHE:
+        # the workers are CPU-only; a parent on a chip would hold it while
+        # they run and report CPU numbers as if they were the chip's
+        require_cpu("benchmarks.scaling")
         _CACHE[quick] = {n: _run_worker(n, quick) for n in device_counts(quick)}
     return _CACHE[quick]
 
@@ -73,6 +84,7 @@ def _run_worker(n_devices: int, quick: bool) -> dict:
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_devices} "
         + env.get("XLA_FLAGS", "")
@@ -200,10 +212,7 @@ def main(argv=None) -> int:
         for n, r in measure(args.quick).items():
             print(json.dumps(r))
         return 0
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.devices} "
-        + os.environ.get("XLA_FLAGS", "")
-    ).strip()
+    force_host_devices(args.devices)
     print(json.dumps(_worker(args.devices, args.quick)))
     return 0
 

@@ -32,7 +32,7 @@ def main() -> None:
     frac = np.bincount(owners, minlength=3) / ids.size
     print(f"distribution: {frac.round(4)} (capacity fractions {np.array([1.5,0.7,1.0])/3.2})")
 
-    # Pallas kernel path (interpret mode on CPU, compiled on TPU)
+    # Pallas kernel path (interpret mode; Mosaic does not lower it on TPU yet)
     owners_k = np.asarray(
         asura_place_nodes(ids[:4096], cluster.seg_lengths(), cluster.seg_to_node())
     )

@@ -22,9 +22,10 @@ STEP 2 dispatches to one of three bit-identical backends:
 
   * ``numpy``  -- vectorized NumPy (the CPU-host default; no device round
                   trip for table or ids),
-  * ``ref``    -- jitted pure-jnp reference,
-  * ``pallas`` -- the Pallas kernel family (the TPU default), including the
-                  section 5.A replica-placement kernel.
+  * ``ref``    -- jitted pure-jnp bodies compiled by XLA (the TPU default),
+  * ``pallas`` -- the Pallas kernel family, including the section 5.A
+                  replica-placement kernel (interpret mode off the TPU;
+                  Mosaic does not lower it yet).
 
 Host-facing methods (``place`` / ``place_nodes`` / ``place_replicas``)
 return NumPy arrays with exactly one device->host transfer on accelerator
@@ -253,11 +254,23 @@ class PlacementEngine:
 
     @property
     def backend(self) -> str:
+        """The STEP-2 backend in use.
+
+        ``"auto"`` resolves once, lazily (only placement imports jax): on a
+        TPU to ``"ref"``, the XLA-compiled jnp path -- Mosaic does not yet
+        lower the Pallas kernels (ROADMAP Speed 1.3), and an explicit
+        ``backend="pallas"`` there fails to compile rather than degrade --
+        and to the host ``"numpy"`` path on every other platform.  The
+        resolution is recorded as an ``engine.backend`` ledger event."""
         if self._backend == "auto":
-            # Lazy: only decide (and import jax) when placement is requested.
             import jax
 
-            self._backend = "pallas" if jax.default_backend() == "tpu" else "numpy"
+            platform = jax.default_backend()
+            self._backend = "ref" if platform == "tpu" else "numpy"
+            self.ledger.event(
+                "engine.backend", self._backend, requested="auto",
+                platform=platform,
+            )
         return self._backend
 
     def _build_device_tables(self, art: TableArtifact) -> TableArtifact:

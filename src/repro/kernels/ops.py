@@ -18,7 +18,8 @@ Two tiers of entry points (DESIGN.md sections 3.2-3.4, 6):
 ``asura_place*`` are the table-deriving conveniences: they canonicalize the
 segment table (via ``core.asura.lengths_to_u32``, which validates lengths
 in [0, 1) exactly like the NumPy path) and dispatch to the kernels --
-Pallas (interpret mode on CPU, compiled on TPU) or the jnp reference.
+Pallas (interpret mode off the TPU; Mosaic does not lower these kernels
+yet, so on a TPU they fail to compile) or the jnp reference.
 """
 
 from __future__ import annotations
@@ -860,8 +861,8 @@ def asura_place(
     """Place a batch of datum ids -> int32 segment numbers (device array).
 
     use_pallas=False routes through the pure-jnp reference (place_ref) --
-    the path the distributed pipeline uses on CPU hosts; the Pallas path is
-    the TPU fast path (validated bit-identical in tests/test_kernels.py).
+    the path the engine runs on a TPU; the Pallas path is validated
+    bit-identical in interpret mode (tests/test_kernels.py).
     The result is total (on-device tail) and stays on device -- no host
     round trip, no result re-upload.
     """

@@ -24,7 +24,7 @@ driver that turns one device's sweep into a mesh sweep:
 Per-id owner/diff arrays come back shard-partitioned (``out_specs
 P('data')``); the host-facing methods re-assemble and trim the pad.
 
-``check_rep=False`` everywhere: the placement kernels are ``while_loop``
+``check_vma=False`` everywhere: the placement kernels are ``while_loop``
 ladders and shard_map has no replication rule for ``while`` -- every
 output is either explicitly partitioned or an explicit ``psum``, so
 nothing relies on the inferred-replication machinery.
@@ -47,6 +47,34 @@ from __future__ import annotations
 import numpy as np
 
 DATA_AXIS = "data"
+
+
+def force_host_devices(n_devices: int) -> None:
+    """Split the host CPU into ``n_devices`` JAX devices (tests, CI and the
+    CPU scaling workers).  Must run before JAX's first backend init.
+    Raises on any platform but the CPU: forced host devices exist only
+    there, and a process holding a chip must not pose as a CPU mesh."""
+    import os
+
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={n_devices} "
+        + os.environ.get("XLA_FLAGS", "")
+    ).strip()
+    require_cpu("forcing host devices")
+
+
+def require_cpu(what: str) -> None:
+    """Raise a directed error unless this process's JAX platform is the
+    CPU (on-chip runs use the devices JAX finds, never forced ones)."""
+    import jax
+
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"{what} needs the CPU platform, but this process runs on "
+            f"{platform!r}; run it with JAX_PLATFORMS=cpu, or drop the "
+            "forced device count to use the devices JAX finds"
+        )
 
 
 def make_data_mesh(n_devices: int | None = None):
@@ -118,19 +146,18 @@ class ShardedSweep:
         outputs either partitioned per-lane arrays or one psum-reduced
         (replicated) array."""
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         in_specs = (P(DATA_AXIS), P(DATA_AXIS)) + (P(),) * n_tables
         one = P() if reduced else P(DATA_AXIS)
         out_specs = one if n_out == 1 else (one,) * n_out
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
-                check_rep=False,  # while_loop ladders have no replication rule
+                check_vma=False,  # while_loop ladders have no replication rule
             )
         )
 
@@ -449,17 +476,52 @@ class ShardedSweep:
 # ---------------------------------------------------------------------------
 
 
-def selftest(n_devices: int | None = None, n_ids: int = 100_003) -> int:
+def check_layout(sweep: ShardedSweep, datum_ids) -> None:
+    """Assert that a mesh sweep really spreads over the mesh: the compiled
+    owner program takes the ids partitioned over the data axis and every
+    table replicated, and its per-id output comes back as one equal shard
+    per device -- nothing lands whole on one device."""
+    out = sweep.place_nodes_device(datum_ids, "asura")
+    tables, statics = sweep._alg_tables("asura")
+    ids, w, _ = sweep._pad(datum_ids)
+    fn = sweep._fns[("owners", "asura", statics)]
+    # (ids, weights, *tables); the owner body never reads the pad weights,
+    # so the compiler prunes that argument and reports no sharding for it
+    in_shardings = fn.lower(ids, w, *tables).compile().input_shardings[0]
+    n = sweep.n_devices
+    assert len(in_shardings) == 2 + len(tables)
+    assert in_shardings[0].shard_shape(ids.shape) == (ids.shape[0] // n,), (
+        f"ids not partitioned over the mesh: {in_shardings[0]}"
+    )
+    for s in in_shardings[2:]:
+        assert s.is_fully_replicated, f"table not replicated: {s}"
+    shards = out.addressable_shards
+    assert len({s.device for s in shards}) == n == len(shards), (
+        "owner shards do not cover every device"
+    )
+    assert all(s.data.shape == (ids.shape[0] // n,) for s in shards)
+
+
+def selftest(
+    n_devices: int | None = None,
+    n_ids: int = 100_003,
+    *,
+    n_nodes: int = 32,
+    serve_nodes: int = 16,
+    serve_batch: int | None = None,
+    n_keys: int = 4096,
+) -> int:
     """Assert sharded == single-device, all four algorithms, R in {1, 3}.
 
     ``n_ids`` is deliberately odd (it must not divide the mesh) so the
-    pad-lane masking is exercised on every entry point.  Returns the
-    device count it ran on.
+    pad-lane masking is exercised on every entry point.  ``n_nodes`` sizes
+    the placement/planner cluster, ``serve_nodes`` / ``serve_batch``
+    (default 256 lanes per device) / ``n_keys`` the serving streams.
+    Returns the device count it ran on.
     """
     from repro.core import PlacementEngine, make_uniform_cluster
     from repro.migrate import MigrationPlanner
 
-    n_nodes = 32
     ids = np.arange(n_ids, dtype=np.uint32)
     mesh = make_data_mesh(n_devices)
 
@@ -478,6 +540,7 @@ def selftest(n_devices: int | None = None, n_ids: int = 100_003) -> int:
 
     engine = PlacementEngine(cluster, backend="ref")
     sweep = ShardedSweep(engine, mesh)
+    check_layout(sweep, ids)
 
     # replica histograms, R in {1, 3}
     for R in (1, 3):
@@ -520,13 +583,13 @@ def selftest(n_devices: int | None = None, n_ids: int = 100_003) -> int:
     # algorithms, R in {1, 3} (DESIGN.md section 12)
     from repro.serve import RequestStreamDriver
 
-    serve_cluster = make_uniform_cluster(16)
-    batch = 256 * int(mesh.devices.size)
+    serve_cluster = make_uniform_cluster(serve_nodes)
+    batch = serve_batch or 256 * int(mesh.devices.size)
     for alg in ("asura", "ch", "wrh", "rs"):
         eng_s = PlacementEngine(serve_cluster, backend="ref", algorithm=alg)
         for R in (1, 3):
             kw = dict(
-                batch=batch, n_keys=4096, law="zipf",
+                batch=batch, n_keys=n_keys, law="zipf",
                 n_replicas=R, policy="pow2", seed=7,
             )
             solo = RequestStreamDriver(eng_s, **kw)
@@ -552,7 +615,7 @@ def selftest(n_devices: int | None = None, n_ids: int = 100_003) -> int:
     eng_m = PlacementEngine(serve_cluster, backend="ref", algorithm="asura")
     for R in (1, 3):
         kw = dict(
-            batch=batch, n_keys=4096, law="zipf",
+            batch=batch, n_keys=n_keys, law="zipf",
             n_replicas=R, policy="pow2", seed=7,
         )
         reg_solo, reg_shard = MetricsRegistry(), MetricsRegistry()
@@ -592,7 +655,7 @@ def selftest(n_devices: int | None = None, n_ids: int = 100_003) -> int:
     )
     for R in (1, 3):
         kw = dict(
-            batch=batch, n_keys=4096, law="zipf",
+            batch=batch, n_keys=n_keys, law="zipf",
             n_replicas=R, policy="pow2", seed=7,
         )
         solo = RequestStreamDriver(heng, **kw)
@@ -610,7 +673,7 @@ def selftest(n_devices: int | None = None, n_ids: int = 100_003) -> int:
     # (DESIGN.md section 15; the per-sub-batch psum stays inside the scan)
     eng_k = PlacementEngine(serve_cluster, backend="ref", algorithm="asura")
     kw = dict(
-        batch=batch, n_keys=4096, law="zipf",
+        batch=batch, n_keys=n_keys, law="zipf",
         n_replicas=3, policy="pow2", seed=7,
     )
     solo = RequestStreamDriver(eng_k, **kw)
@@ -618,7 +681,14 @@ def selftest(n_devices: int | None = None, n_ids: int = 100_003) -> int:
     k = 3
     for _block in range(2):
         a = np.stack([np.asarray(solo.step()) for _ in range(k)])
-        b = np.asarray(shard.superstep(k))
+        chosen = shard.superstep(k)
+        # the chosen lanes come back one (k, batch / n) shard per device
+        shards = chosen.addressable_shards
+        assert len({s.device for s in shards}) == sweep.n_devices == len(shards)
+        assert all(
+            s.data.shape == (k, batch // sweep.n_devices) for s in shards
+        ), "sharded superstep output is not lane-partitioned"
+        b = np.asarray(chosen)
         assert np.array_equal(a, b), (
             f"block {_block}: sharded superstep chosen nodes differ"
         )
@@ -633,7 +703,6 @@ def selftest(n_devices: int | None = None, n_ids: int = 100_003) -> int:
 
 def main(argv=None) -> int:
     import argparse
-    import os
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--selftest", action="store_true")
@@ -646,10 +715,7 @@ def main(argv=None) -> int:
     ap.add_argument("--ids", type=int, default=100_003)
     args = ap.parse_args(argv)
     if args.devices is not None:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices} "
-            + os.environ.get("XLA_FLAGS", "")
-        ).strip()
+        force_host_devices(args.devices)
     if not args.selftest:
         print("nothing to do (pass --selftest)")
         return 0

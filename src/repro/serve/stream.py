@@ -438,14 +438,13 @@ class RequestStreamDriver:
 
         if sweep is None:
             return jax.jit(stepped)
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.launch.placement_mesh import DATA_AXIS
 
         n_in, n_rep_out = self._spec_counts(statics)
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 stepped,
                 mesh=sweep.mesh,
                 # everything replicated: lanes derive from axis_index, so
@@ -453,7 +452,7 @@ class RequestStreamDriver:
                 # lanes come back shard-partitioned.
                 in_specs=(P(),) * n_in,
                 out_specs=(P(),) * (n_rep_out + 1) + (P(DATA_AXIS),),
-                check_rep=False,  # while_loop ladders have no replication rule
+                check_vma=False,  # while_loop ladders have no replication rule
             )
         )
 
@@ -583,21 +582,20 @@ class RequestStreamDriver:
 
         if sweep is None:
             return jax.jit(super_body)
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.launch.placement_mesh import DATA_AXIS
 
         n_in, n_rep_out = self._spec_counts(statics)
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 super_body,
                 mesh=sweep.mesh,
                 in_specs=(P(),) * n_in,
                 # stacked chosen is (k, local): partitioned on the LANE
                 # axis, replicated over the scan axis.
                 out_specs=(P(),) * (n_rep_out + 1) + (P(None, DATA_AXIS),),
-                check_rep=False,
+                check_vma=False,
             )
         )
 

@@ -148,6 +148,26 @@ def test_rejects_non_data_mesh(versions):
         ShardedSweep(engine, bad)
 
 
+def test_forced_host_devices_refused_off_cpu(monkeypatch):
+    """On a chip, neither the selftest CLI nor the CPU scaling workers may
+    force host devices: both raise before any child or compile starts."""
+    import jax
+
+    from benchmarks import scaling
+    from repro.launch.placement_mesh import force_host_devices
+
+    monkeypatch.setenv("XLA_FLAGS", "")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="needs the CPU platform"):
+        force_host_devices(4)
+    monkeypatch.setattr(scaling, "_CACHE", {})
+    monkeypatch.setattr(
+        scaling, "_run_worker", lambda *a: pytest.fail("worker started")
+    )
+    with pytest.raises(RuntimeError, match="needs the CPU platform"):
+        scaling.measure(quick=True)
+
+
 # ---------------------------------------------------------------------------
 # pow2 tail bucketing of the streaming planner (satellite: no phantom moves)
 # ---------------------------------------------------------------------------
