@@ -498,15 +498,15 @@ class CheckpointManager:
                 yield chunk_id(step, li, ci), blob
 
     def save(self, step: int, tree: Any) -> None:
-        from repro.obs.trace import maybe_span
+        from repro.obs.trace import span
 
         keys, blobs = [], []
         for key, blob in self._chunks_of(step, tree):
             keys.append(key)
             blobs.append(blob)
-        with maybe_span(
-            self.ledger,
+        with span(
             "checkpoint.save",
+            self.ledger,
             step=step,
             n_chunks=len(keys),
             n_bytes=sum(len(b) for b in blobs),
@@ -540,12 +540,12 @@ class CheckpointManager:
 
     def restore(self, step: int, like: Any) -> Any:
         """Rebuild a pytree shaped like ``like`` from the store."""
-        from repro.obs.trace import maybe_span
+        from repro.obs.trace import span
 
         leaves, treedef = jax.tree.flatten(like)
         out = []
         n_chunks = n_bytes = 0
-        with maybe_span(self.ledger, "checkpoint.restore", step=step):
+        with span("checkpoint.restore", self.ledger, step=step):
             for li, leaf in enumerate(leaves):
                 arr = np.asarray(leaf)
                 raw = arr.tobytes()

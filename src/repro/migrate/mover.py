@@ -29,6 +29,8 @@ from typing import Callable
 
 import numpy as np
 
+from repro.obs.trace import span
+
 from .drain import DrainDriver
 from .planner import MigrationPlan
 
@@ -443,41 +445,42 @@ class ThrottledMover(DrainDriver):
             if n == 0:
                 self._dev_rounds = False
             else:
-                import jax.numpy as jnp
+                with span("mover.prepare"):
+                    import jax.numpy as jnp
 
-                P = 1 << max(0, n - 1).bit_length()
-                no_key = np.iinfo(np.int64).max  # pads sort last
-                i32max = np.iinfo(np.int32).max
+                    P = 1 << max(0, n - 1).bit_length()
+                    no_key = np.iinfo(np.int64).max  # pads sort last
+                    i32max = np.iinfo(np.int32).max
 
-                def axis(keys, caps):
-                    kp = np.full(P, no_key, dtype=np.int64)
-                    kp[:n] = keys
-                    order = np.argsort(kp, kind="stable")
-                    sk = kp[order]
-                    is_start = np.empty(P, dtype=bool)
-                    is_start[0] = True
-                    np.not_equal(sk[1:], sk[:-1], out=is_start[1:])
-                    cp = np.zeros(P, dtype=np.int64)
-                    cp[:n] = np.minimum(caps, i32max)
-                    return (
-                        jnp.asarray(order.astype(np.int32)),
-                        jnp.asarray(is_start),
-                        jnp.asarray(cp.astype(np.int32)),
-                    )
+                    def axis(keys, caps):
+                        kp = np.full(P, no_key, dtype=np.int64)
+                        kp[:n] = keys
+                        order = np.argsort(kp, kind="stable")
+                        sk = kp[order]
+                        is_start = np.empty(P, dtype=bool)
+                        is_start[0] = True
+                        np.not_equal(sk[1:], sk[:-1], out=is_start[1:])
+                        cp = np.zeros(P, dtype=np.int64)
+                        cp[:n] = np.minimum(caps, i32max)
+                        return (
+                            jnp.asarray(order.astype(np.int32)),
+                            jnp.asarray(is_start),
+                            jnp.asarray(cp.astype(np.int32)),
+                        )
 
-                n_bins = int(max(plan.src.max(), plan.dst.max())) + 1
-                coord = np.zeros((2, P), dtype=np.int32)
-                coord[0, :n] = plan.src
-                coord[1, :n] = plan.dst
-                self._dev_rounds = {
-                    "src": axis(plan.src, self._cap_src),
-                    "dst": axis(plan.dst, self._cap_dst),
-                    "valid": jnp.asarray(np.arange(P) < n),
-                    "src_c": jnp.asarray(coord[0]),
-                    "dst_c": jnp.asarray(coord[1]),
-                    "n_bins": n_bins,
-                    "P": P,
-                }
+                    n_bins = int(max(plan.src.max(), plan.dst.max())) + 1
+                    coord = np.zeros((2, P), dtype=np.int32)
+                    coord[0, :n] = plan.src
+                    coord[1, :n] = plan.dst
+                    self._dev_rounds = {
+                        "src": axis(plan.src, self._cap_src),
+                        "dst": axis(plan.dst, self._cap_dst),
+                        "valid": jnp.asarray(np.arange(P) < n),
+                        "src_c": jnp.asarray(coord[0]),
+                        "dst_c": jnp.asarray(coord[1]),
+                        "n_bins": n_bins,
+                        "P": P,
+                    }
         return self._dev_rounds or None
 
     def _block_fn(self, k: int):
@@ -523,20 +526,22 @@ class ThrottledMover(DrainDriver):
         dv = self._device_rounds()
         P, n = dv["P"], state.plan.n_moves
         landed = state.landed if n == P else np.pad(state.landed, (0, P - n))
-        landed_out, mats = self._block_fn(k)(jnp.asarray(landed))
-        landed_np = np.asarray(landed_out)[:n]
-        mats_np = np.asarray(mats)
-        newly = landed_np & ~state.landed
-        state.mark_landed(np.nonzero(newly)[0])
-        matrices: list[dict[tuple[int, int], int]] = []
-        for r in range(k):
-            s_idx, d_idx = np.nonzero(mats_np[r])
-            matrices.append(
-                {
-                    (int(s), int(d)): int(mats_np[r, s, d])
-                    for s, d in zip(s_idx, d_idx)
-                }
-            )
+        with span("mover.scan"):
+            landed_out, mats = self._block_fn(k)(jnp.asarray(landed))
+            landed_np = np.asarray(landed_out)[:n]
+            mats_np = np.asarray(mats)
+        with span("mover.matrices"):
+            newly = landed_np & ~state.landed
+            state.mark_landed(np.nonzero(newly)[0])
+            matrices: list[dict[tuple[int, int], int]] = []
+            for r in range(k):
+                s_idx, d_idx = np.nonzero(mats_np[r])
+                matrices.append(
+                    {
+                        (int(s), int(d)): int(mats_np[r, s, d])
+                        for s, d in zip(s_idx, d_idx)
+                    }
+                )
         self.rounds_done += k
         self.history.extend(matrices)
         return matrices
@@ -548,7 +553,10 @@ class ThrottledMover(DrainDriver):
         k = int(k)
         if k < 1:
             raise ValueError(f"round_block needs k >= 1, got {k}")
-        return self._emit_rounds(self._advance(lambda: self._round_block(k)))
+        with span("mover.round_block"):
+            return self._emit_rounds(
+                self._advance(lambda: self._round_block(k))
+            )
 
     def movement_matrix(self) -> dict[tuple[int, int], int]:
         """Accumulated (src, dst) -> rows moved so far, across all rounds."""

@@ -37,6 +37,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.obs.trace import span
+
 DEFAULT_CHUNK = 1 << 20  # ids per streaming chunk (fixed device memory)
 
 _MASK_CACHE: dict = {}
@@ -81,6 +83,14 @@ def _mask_tail(moved, n_valid: int):
 
         _MASK_CACHE[moved.ndim] = fn
     return fn(moved, n_valid)
+
+
+def _plan_fields(plan) -> dict:
+    """The fields of a plan's ``planner.*`` span event."""
+    return {
+        "n_scanned": plan.n_scanned, "n_moves": plan.n_moves,
+        "v_from": plan.v_from, "v_to": plan.v_to,
+    }
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,17 +180,6 @@ class MigrationPlanner:
         if self.metrics is not None:
             self.metrics.inc_host("planner.prefilter_scanned", n_scanned)
             self.metrics.inc_host("planner.prefilter_kept", n_kept)
-
-    def _note_plan(self, kind: str, plan, t0: float) -> None:
-        if self.ledger is None:
-            return
-        import time
-
-        self.ledger.event(
-            "span", kind, dur_s=float(time.perf_counter() - t0),
-            n_scanned=plan.n_scanned, n_moves=plan.n_moves,
-            v_from=plan.v_from, v_to=plan.v_to,
-        )
 
     def _sweep(self, mesh):
         """Resolve ``mesh=`` (a Mesh, a ``ShardedSweep``, or None) into a
@@ -441,69 +440,67 @@ class MigrationPlanner:
         bit-identical (DESIGN.md section 11); it forces the device path
         regardless of backend.
         """
-        import time
-
-        t0 = time.perf_counter()
-        ids = np.atleast_1d(np.asarray(datum_ids, dtype=np.uint32))
-        sweep = self._sweep(mesh)
-        host = self.engine.backend == "numpy" and sweep is None
-        if known_src is not None:
-            known_src = np.asarray(known_src, dtype=np.int64)
-        out_ids: list[np.ndarray] = []
-        out_src: list[np.ndarray] = []
-        out_dst: list[np.ndarray] = []
-        out_idx: list[np.ndarray] = []
-        for start in range(0, len(ids), chunk):
-            c = ids[start : start + chunk]
-            base = np.arange(start, start + len(c), dtype=np.int64)
-            if max_new_seg is not None:
-                keep = self._candidates(c, v_from, max_new_seg, host)
-                self._note_prefilter(len(keep), int(keep.sum()))
-                c, base = c[keep], base[keep]
-            if c.size == 0:
-                continue
-            if host:
-                src = (
-                    known_src[base]
-                    if known_src is not None
-                    else self.engine.place_nodes_at(c, v_from)
-                )
-                dst = self.engine.place_nodes_at(c, v_to)
-                moved = src != dst
-            else:
-                # Pad ragged (prefiltered) chunks to the next power of two
-                # so the jitted diff sees O(log chunk) distinct shapes, not
-                # one compile per candidate count.
-                n_c = len(c)
-                cp, _ = self._pad_pow2(
-                    c, 1 if sweep is None else sweep.n_devices
-                )
-                if sweep is None:
-                    moved_d, src_d, dst_d = self.diff_device(cp, v_from, v_to)
-                else:
-                    moved_d, src_d, dst_d = sweep.diff_nodes_device(
-                        cp, v_from, v_to
+        with span("planner.plan", self.ledger) as fields:
+            ids = np.atleast_1d(np.asarray(datum_ids, dtype=np.uint32))
+            sweep = self._sweep(mesh)
+            host = self.engine.backend == "numpy" and sweep is None
+            if known_src is not None:
+                known_src = np.asarray(known_src, dtype=np.int64)
+            out_ids: list[np.ndarray] = []
+            out_src: list[np.ndarray] = []
+            out_dst: list[np.ndarray] = []
+            out_idx: list[np.ndarray] = []
+            for start in range(0, len(ids), chunk):
+                c = ids[start : start + chunk]
+                base = np.arange(start, start + len(c), dtype=np.int64)
+                if max_new_seg is not None:
+                    keep = self._candidates(c, v_from, max_new_seg, host)
+                    self._note_prefilter(len(keep), int(keep.sum()))
+                    c, base = c[keep], base[keep]
+                if c.size == 0:
+                    continue
+                if host:
+                    src = (
+                        known_src[base]
+                        if known_src is not None
+                        else self.engine.place_nodes_at(c, v_from)
                     )
-                moved = np.asarray(moved_d)[:n_c]
-                src = np.asarray(src_d)[:n_c].astype(np.int64)
-                dst = np.asarray(dst_d)[:n_c].astype(np.int64)
-            out_ids.append(c[moved])
-            out_src.append(src[moved])
-            out_dst.append(dst[moved])
-            out_idx.append(base[moved])
-        cat = lambda parts, dtype: (  # noqa: E731
-            np.concatenate(parts) if parts else np.zeros(0, dtype=dtype)
-        )
-        plan = MigrationPlan(
-            v_from=v_from,
-            v_to=v_to,
-            ids=cat(out_ids, np.uint32),
-            src=cat(out_src, np.int64),
-            dst=cat(out_dst, np.int64),
-            index=cat(out_idx, np.int64),
-            n_scanned=len(ids),
-        )
-        self._note_plan("planner.plan", plan, t0)
+                    dst = self.engine.place_nodes_at(c, v_to)
+                    moved = src != dst
+                else:
+                    # Pad ragged (prefiltered) chunks to the next power of two
+                    # so the jitted diff sees O(log chunk) distinct shapes, not
+                    # one compile per candidate count.
+                    n_c = len(c)
+                    cp, _ = self._pad_pow2(
+                        c, 1 if sweep is None else sweep.n_devices
+                    )
+                    if sweep is None:
+                        moved_d, src_d, dst_d = self.diff_device(cp, v_from, v_to)
+                    else:
+                        moved_d, src_d, dst_d = sweep.diff_nodes_device(
+                            cp, v_from, v_to
+                        )
+                    moved = np.asarray(moved_d)[:n_c]
+                    src = np.asarray(src_d)[:n_c].astype(np.int64)
+                    dst = np.asarray(dst_d)[:n_c].astype(np.int64)
+                out_ids.append(c[moved])
+                out_src.append(src[moved])
+                out_dst.append(dst[moved])
+                out_idx.append(base[moved])
+            cat = lambda parts, dtype: (  # noqa: E731
+                np.concatenate(parts) if parts else np.zeros(0, dtype=dtype)
+            )
+            plan = MigrationPlan(
+                v_from=v_from,
+                v_to=v_to,
+                ids=cat(out_ids, np.uint32),
+                src=cat(out_src, np.int64),
+                dst=cat(out_dst, np.int64),
+                index=cat(out_idx, np.int64),
+                n_scanned=len(ids),
+            )
+            fields.update(_plan_fields(plan))
         return plan
 
     def plan_replicas(
@@ -535,88 +532,98 @@ class MigrationPlanner:
         two placement sweeps.  ``mesh=`` scales the dual replica diff over
         the mesh's data axis, bit-identically, as in ``plan``.
         """
-        import time
-
-        t0 = time.perf_counter()
-        ids = np.atleast_1d(np.asarray(datum_ids, dtype=np.uint32))
-        sweep = self._sweep(mesh)
         hier = bool(getattr(self.engine, "hierarchical", False))
         if hier and max_new_seg is not None:
             raise ValueError(
                 "the ADDITION-NUMBER prefilter is flat-table semantics; "
                 "hierarchical plans scan the full id set (max_new_seg=None)"
             )
-        # Hierarchical engines always diff through the fused two-level
-        # kernel path (node-plane alignment, domains validated globally
-        # unique) -- the host replica sweep returns (batch, R, 2) pairs.
-        host = self.engine.backend == "numpy" and sweep is None and not hier
-        if known_before is not None:
-            known_before = np.asarray(known_before, dtype=np.int64)
-        out: dict[str, list[np.ndarray]] = {
-            k: [] for k in ("ids", "src", "dst", "idx", "slot", "src_slot")
-        }
-        for start in range(0, len(ids), chunk):
-            c = ids[start : start + chunk]
-            base = np.arange(start, start + len(c), dtype=np.int64)
-            if max_new_seg is not None:
-                keep = self._candidates(
-                    c, v_from, max_new_seg, host, n_replicas=n_replicas
-                )
-                self._note_prefilter(len(keep), int(keep.sum()))
-                c, base = c[keep], base[keep]
-            if c.size == 0:
-                continue
-            if host:
-                from repro.core.asura import align_replica_sets
+        with span("planner.plan_replicas", self.ledger) as fields:
+            ids = np.atleast_1d(np.asarray(datum_ids, dtype=np.uint32))
+            sweep = self._sweep(mesh)
+            # Hierarchical engines always diff through the fused two-level
+            # kernel path (node-plane alignment, domains validated globally
+            # unique) -- the host replica sweep returns (batch, R, 2) pairs.
+            host = self.engine.backend == "numpy" and sweep is None and not hier
+            if known_before is not None:
+                known_before = np.asarray(known_before, dtype=np.int64)
+            out: dict[str, list[np.ndarray]] = {
+                k: [] for k in ("ids", "src", "dst", "idx", "slot", "src_slot")
+            }
+            for start in range(0, len(ids), chunk):
+                c = ids[start : start + chunk]
+                base = np.arange(start, start + len(c), dtype=np.int64)
+                if max_new_seg is not None:
+                    with span("planner.prefilter"):
+                        keep = self._candidates(
+                            c, v_from, max_new_seg, host, n_replicas=n_replicas
+                        )
+                        self._note_prefilter(len(keep), int(keep.sum()))
+                        c, base = c[keep], base[keep]
+                if c.size == 0:
+                    continue
+                if host:
+                    from repro.core.asura import align_replica_sets
 
-                before = (
-                    known_before[base]
-                    if known_before is not None
-                    else self.engine.place_replica_nodes_at(c, v_from, n_replicas)
-                )
-                dst = self.engine.place_replica_nodes_at(c, v_to, n_replicas)
-                moved, src, src_slot = align_replica_sets(before, dst)
-            else:
-                # pow2-bucketed ragged chunks, as in ``plan``
-                n_c = len(c)
-                cp, _ = self._pad_pow2(
-                    c, 1 if sweep is None else sweep.n_devices
-                )
-                if sweep is None:
-                    moved_d, src_d, dst_d, slot_d = self.diff_replicas_device(
-                        cp, v_from, v_to, n_replicas
+                    before = (
+                        known_before[base]
+                        if known_before is not None
+                        else self.engine.place_replica_nodes_at(
+                            c, v_from, n_replicas
+                        )
                     )
+                    dst = self.engine.place_replica_nodes_at(c, v_to, n_replicas)
+                    moved, src, src_slot = align_replica_sets(before, dst)
                 else:
-                    moved_d, src_d, dst_d, slot_d = sweep.diff_replicas_device(
-                        cp, v_from, v_to, n_replicas
+                    with span("planner.diff"):
+                        # pow2-bucketed ragged chunks, as in ``plan``
+                        n_c = len(c)
+                        cp, _ = self._pad_pow2(
+                            c, 1 if sweep is None else sweep.n_devices
+                        )
+                        if sweep is None:
+                            moved_d, src_d, dst_d, slot_d = (
+                                self.diff_replicas_device(
+                                    cp, v_from, v_to, n_replicas
+                                )
+                            )
+                        else:
+                            moved_d, src_d, dst_d, slot_d = (
+                                sweep.diff_replicas_device(
+                                    cp, v_from, v_to, n_replicas
+                                )
+                            )
+                        moved = np.asarray(moved_d)[:n_c]
+                        src = np.asarray(src_d)[:n_c].astype(np.int64)
+                        dst = np.asarray(dst_d)[:n_c].astype(np.int64)
+                        src_slot = np.asarray(slot_d)[:n_c]
+                with span("planner.assemble"):
+                    b_idx, r_idx = np.nonzero(moved)  # id-major, slot-minor
+                    out["ids"].append(c[b_idx])
+                    out["src"].append(src[b_idx, r_idx])
+                    out["dst"].append(dst[b_idx, r_idx])
+                    out["idx"].append(base[b_idx])
+                    out["slot"].append(r_idx.astype(np.int32))
+                    out["src_slot"].append(
+                        src_slot[b_idx, r_idx].astype(np.int32)
                     )
-                moved = np.asarray(moved_d)[:n_c]
-                src = np.asarray(src_d)[:n_c].astype(np.int64)
-                dst = np.asarray(dst_d)[:n_c].astype(np.int64)
-                src_slot = np.asarray(slot_d)[:n_c]
-            b_idx, r_idx = np.nonzero(moved)  # id-major, slot-minor
-            out["ids"].append(c[b_idx])
-            out["src"].append(src[b_idx, r_idx])
-            out["dst"].append(dst[b_idx, r_idx])
-            out["idx"].append(base[b_idx])
-            out["slot"].append(r_idx.astype(np.int32))
-            out["src_slot"].append(src_slot[b_idx, r_idx].astype(np.int32))
-        cat = lambda parts, dtype: (  # noqa: E731
-            np.concatenate(parts) if parts else np.zeros(0, dtype=dtype)
-        )
-        plan = MigrationPlan(
-            v_from=v_from,
-            v_to=v_to,
-            ids=cat(out["ids"], np.uint32),
-            src=cat(out["src"], np.int64),
-            dst=cat(out["dst"], np.int64),
-            index=cat(out["idx"], np.int64),
-            n_scanned=len(ids),
-            n_replicas=n_replicas,
-            slot=cat(out["slot"], np.int32),
-            src_slot=cat(out["src_slot"], np.int32),
-        )
-        self._note_plan("planner.plan_replicas", plan, t0)
+            cat = lambda parts, dtype: (  # noqa: E731
+                np.concatenate(parts) if parts else np.zeros(0, dtype=dtype)
+            )
+            with span("planner.assemble"):
+                plan = MigrationPlan(
+                    v_from=v_from,
+                    v_to=v_to,
+                    ids=cat(out["ids"], np.uint32),
+                    src=cat(out["src"], np.int64),
+                    dst=cat(out["dst"], np.int64),
+                    index=cat(out["idx"], np.int64),
+                    n_scanned=len(ids),
+                    n_replicas=n_replicas,
+                    slot=cat(out["slot"], np.int32),
+                    src_slot=cat(out["src_slot"], np.int32),
+                )
+            fields.update(_plan_fields(plan))
         return plan
 
     def _candidates(

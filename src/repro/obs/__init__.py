@@ -9,12 +9,13 @@ into host uint64 totals (DESIGN.md section 13).
 Host plane (``obs.trace``): ``TraceLedger`` records timestamped
 structured events (spans, uploads, jit traces, migration rounds) plus
 monotonically-increasing host counters, with JSONL and Prometheus-style
-text exporters.  The three ad-hoc trace tripwires (``engine.uploads``,
+text exporters; ``span`` also writes each span into a running profiler
+trace.  The three ad-hoc trace tripwires (``engine.uploads``,
 ``RequestStreamDriver.step_traces``, the window/router probe counters)
 are ledger counters behind back-compat aliases.
 """
 
 from .metrics import MetricsRegistry
-from .trace import TraceLedger, get_ledger, set_ledger
+from .trace import TraceLedger, get_ledger, set_ledger, span
 
-__all__ = ["MetricsRegistry", "TraceLedger", "get_ledger", "set_ledger"]
+__all__ = ["MetricsRegistry", "TraceLedger", "get_ledger", "set_ledger", "span"]

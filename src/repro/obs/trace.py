@@ -13,7 +13,10 @@ Counters are cheap (one dict update -- safe inside traced-body Python
 side effects, which fire once per TRACE); events carry a timestamp from
 an injectable clock (tests pass a fake) and export as JSONL (one object
 per line) or Prometheus-style text exposition, optionally merged with a
-``MetricsRegistry``'s drained device totals.
+``MetricsRegistry``'s drained device totals.  Spans (``span``, and
+``TraceLedger.span`` through it) are also ``jax.profiler.TraceAnnotation``s,
+so a profiler trace shows each one on the host thread beside the device's
+work; their names are the program's API.
 
 A module-level ledger (``get_ledger()``) serves call sites with no
 instance to hang state on (the migration window's module-level probe
@@ -91,14 +94,10 @@ class TraceLedger:
             return evs
         return [e for e in evs if e["kind"] == kind]
 
-    @contextlib.contextmanager
     def span(self, name: str, **fields):
-        """Time a block; emits one ``kind="span"`` event with ``dur_s``."""
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            self.event("span", name, dur_s=float(self._clock() - t0), **fields)
+        """Time a block; emits one ``kind="span"`` event with ``dur_s``
+        (the module-level ``span`` with this ledger)."""
+        return span(name, self, **fields)
 
     def clear(self) -> None:
         self._events.clear()
@@ -172,8 +171,25 @@ def set_ledger(ledger: TraceLedger) -> TraceLedger:
     return prev
 
 
-def maybe_span(ledger, name: str, **fields):
-    """``ledger.span`` when a ledger is present, else a no-op context."""
-    if ledger is None:
-        return contextlib.nullcontext()
-    return ledger.span(name, **fields)
+@contextlib.contextmanager
+def span(name: str, ledger: TraceLedger | None = None, **fields):
+    """The program's one span: a ``jax.profiler.TraceAnnotation`` named
+    ``name`` around the block, so a profiler trace shows it on the host
+    thread that ran it, on the device trace's clock (with no profiler
+    running the annotation costs about a microsecond).
+
+    Given a ``ledger``, the block also appends one ``kind="span"`` ring
+    event with ``dur_s`` on the ledger's clock.  The context yields the
+    event's ``fields`` dict, so the block can add what it learns (a
+    plan's row count) before the event is written."""
+    import jax
+
+    with jax.profiler.TraceAnnotation(name):
+        if ledger is None:
+            yield fields
+            return
+        t0 = ledger._clock()
+        try:
+            yield fields
+        finally:
+            ledger.event("span", name, dur_s=float(ledger._clock() - t0), **fields)

@@ -45,6 +45,7 @@ import math
 import numpy as np
 
 from repro.migrate.planner import pad_pow2
+from repro.obs.trace import span
 
 from .traffic import TrafficModel
 
@@ -723,26 +724,27 @@ class RequestStreamDriver:
                 "route_batch serves host-fed batches single-device; "
                 "mesh-sharded serving goes through step()"
             )
-        ids = jnp.asarray(datum_ids)
-        n = int(ids.shape[0])
-        padded, n_valid = pad_pow2(ids)
-        tables, statics = route_statics(self.engine, self.algorithm)
-        fn = self._cached(("route_batch", statics), lambda: self._route_batch_fn(statics))
-        if self._instrumented:
-            (self.counts, self.queue, self.qhist, slab, self._step,
-             chosen) = fn(
-                padded, jnp.uint32(n_valid), self._key, self._step,
-                self.counts, self.queue, self.qhist, self.metrics.slab(),
-                self._service, *tables,
-            )
-            self.metrics.set_slab(slab)
-        else:
-            self.counts, self.queue, self.qhist, self._step, chosen = fn(
-                padded, jnp.uint32(n_valid), self._key, self._step,
-                self.counts, self.queue, self.qhist, self._service, *tables,
-            )
-        self.steps_done += 1
-        return _head(chosen, n)
+        with span("serve.route_batch"):
+            ids = jnp.asarray(datum_ids)
+            n = int(ids.shape[0])
+            padded, n_valid = pad_pow2(ids)
+            tables, statics = route_statics(self.engine, self.algorithm)
+            fn = self._cached(("route_batch", statics), lambda: self._route_batch_fn(statics))
+            if self._instrumented:
+                (self.counts, self.queue, self.qhist, slab, self._step,
+                 chosen) = fn(
+                    padded, jnp.uint32(n_valid), self._key, self._step,
+                    self.counts, self.queue, self.qhist, self.metrics.slab(),
+                    self._service, *tables,
+                )
+                self.metrics.set_slab(slab)
+            else:
+                self.counts, self.queue, self.qhist, self._step, chosen = fn(
+                    padded, jnp.uint32(n_valid), self._key, self._step,
+                    self.counts, self.queue, self.qhist, self._service, *tables,
+                )
+            self.steps_done += 1
+            return _head(chosen, n)
 
     # -- serving through a live migration window ------------------------------
 
