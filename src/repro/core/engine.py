@@ -1129,11 +1129,10 @@ class PlacementEngine:
     ):
         """Device-resident section 2.D ADDITION NUMBERs -> int32 device array.
 
-        The planner's add-node prefilter: computed against the (cached)
-        ``version`` table (default: current).  -1 means "unknown, treat as
-        candidate" -- the exact-fallback lanes the NumPy batch resolves via
-        the scalar oracle would force a host sync here (see
-        ``addition_numbers_ref``)."""
+        Computed against the (cached) ``version`` table (default:
+        current).  -1 means "unknown, treat as candidate" -- the
+        exact-fallback lanes the NumPy batch resolves via the scalar
+        oracle would force a host sync here (see ``addition_numbers_ref``)."""
         from repro.kernels.ops import addition_numbers_on_table_device
 
         self._require_asura("addition_numbers_device")

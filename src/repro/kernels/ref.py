@@ -321,11 +321,10 @@ def addition_numbers_ref(
 ) -> jax.Array:
     """Device-resident section 2.D ADDITION NUMBER -> (batch,) int32.
 
-    The migration planner's prefilter variant of
-    ``repro.core.asura.addition_numbers_batch``: every lane runs the bounded
-    replica trace on device, tracking the minimum *unused* anterior ASURA
-    number as an exact ``(k, frac32)`` lexicographic pair (no u64 needed, so
-    it runs on TPUs).  Where the NumPy batch falls back to the exact scalar
+    The device twin of ``repro.core.asura.addition_numbers_batch``: every
+    lane runs the bounded replica trace on device, tracking the minimum
+    *unused* anterior ASURA number as an exact ``(k, frac32)``
+    lexicographic pair (no u64 needed, so it runs on TPUs).  Where the NumPy batch falls back to the exact scalar
     oracle (non-convergence, or the rare range-extension case where every
     anterior number was used), this returns ``-1`` -- checking would force a
     host sync.  ``-1`` means "unknown: treat as a candidate", which keeps

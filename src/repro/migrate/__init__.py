@@ -4,7 +4,7 @@ Three layers over one membership change v -> v+1:
 
   1. ``MigrationPlanner``  -- streaming version-diff planner: places every
      id under both cached table versions in one device pass (fused
-     dual-table kernel, ADDITION-NUMBER prefilter for add-node events) and
+     dual-table kernel, owner prefilter for add-node events) and
      emits the minimal ``MigrationPlan``.
   2. ``ThrottledMover``    -- drains the plan in rounds under per-node
      ingress/egress budgets (simulated clock), maintaining the landed

@@ -15,10 +15,13 @@ DESIGN.md section 6) and diffing the owners:
                          a transfer guard).
   * ``plan``          -- host-facing assembly into a ``MigrationPlan``
                          (the moved rows only).  For the common add-node
-                         case, pass ``max_new_seg`` to enable the
-                         device-side ADDITION-NUMBER prefilter (section
-                         2.D): a cheap metadata sweep marks the candidate
-                         set and only candidates pay the full dual diff.
+                         case, pass ``max_new_seg`` to enable the OWNER
+                         prefilter: the grown nodes are read off the two
+                         cached artifacts, one placement under v+1 keeps
+                         the ids whose set holds one, and only those pay
+                         the full dual diff.  Exact for a pure addition
+                         (minimal movement, section 6.D); any change that
+                         shrinks or reassigns a segment keeps every id.
 
 The unit of work generalizes from a node to an R-way REPLICA SET
 (DESIGN.md section 10): ``diff_replicas_device`` / ``plan_replicas_stream``
@@ -166,8 +169,8 @@ class MigrationPlanner:
     def __init__(self, engine, *, ledger=None, metrics=None):
         self.engine = engine
         # observability (optional): spans around plan assembly plus the
-        # ADDITION-NUMBER prefilter's scanned/kept counters (its hit rate
-        # is the section-2.D fast path's effectiveness, DESIGN.md 13).
+        # owner prefilter's scanned/kept counters (its hit rate is the
+        # add-node fast path's effectiveness, DESIGN.md 13).
         self.ledger = ledger
         self.metrics = metrics
         # scan-fused multi-chunk diff jits, keyed (kind, statics[, R])
@@ -421,11 +424,12 @@ class MigrationPlanner:
         """Assemble the full ``MigrationPlan`` for a tracked id set.
 
         ``max_new_seg`` (the largest segment number the v -> v+1 change
-        assigned; add-node events know it) enables the ADDITION-NUMBER
-        prefilter: a device metadata sweep computes each id's AN against
-        the v table and only ids with AN <= max_new_seg (or AN unknown,
-        the sound fallback) pay the full dual-version diff -- the paper's
-        section 2.D fast path for the common scale-out event.
+        assigned; add-node events know it) enables the owner prefilter:
+        one placement under v+1 keeps the ids owned by a node that gained
+        segments (or not converged, the sound fallback), and only those pay
+        the full dual-version diff -- the fast path for the common
+        scale-out event.  ``max_new_seg`` is cross-checked against the
+        artifacts; a change that is not a pure addition keeps every id.
 
         ``known_src`` (aligned with ``datum_ids``) supplies the v owners a
         caller already maintains (``ElasticCoordinator``'s owner table), so
@@ -450,13 +454,13 @@ class MigrationPlanner:
             out_src: list[np.ndarray] = []
             out_dst: list[np.ndarray] = []
             out_idx: list[np.ndarray] = []
+            grown = self._grown_nodes(v_from, v_to, max_new_seg, fields)
             for start in range(0, len(ids), chunk):
                 c = ids[start : start + chunk]
                 base = np.arange(start, start + len(c), dtype=np.int64)
                 if max_new_seg is not None:
-                    keep = self._candidates(c, v_from, max_new_seg, host)
-                    self._note_prefilter(len(keep), int(keep.sum()))
-                    c, base = c[keep], base[keep]
+                    with span("planner.prefilter"):
+                        c, base = self._prefilter(c, base, v_to, grown, host, 1)
                 if c.size == 0:
                     continue
                 if host:
@@ -525,19 +529,14 @@ class MigrationPlanner:
         per id, the paper's section-5 minimal replica mass; common nodes
         that merely changed position inside the set move nothing.
 
-        ``max_new_seg`` enables the R-aware ADDITION-NUMBER prefilter (the
-        replica trace's AN; sound, plan-preserving).  ``known_before``
+        ``max_new_seg`` enables the owner prefilter on the v+1 replica
+        sets (exact, plan-preserving; see ``plan``).  ``known_before``
         (aligned (len(ids), R) v replica sets a caller already maintains,
         e.g. the coordinator's owner table) saves the host path one of the
         two placement sweeps.  ``mesh=`` scales the dual replica diff over
         the mesh's data axis, bit-identically, as in ``plan``.
         """
         hier = bool(getattr(self.engine, "hierarchical", False))
-        if hier and max_new_seg is not None:
-            raise ValueError(
-                "the ADDITION-NUMBER prefilter is flat-table semantics; "
-                "hierarchical plans scan the full id set (max_new_seg=None)"
-            )
         with span("planner.plan_replicas", self.ledger) as fields:
             ids = np.atleast_1d(np.asarray(datum_ids, dtype=np.uint32))
             sweep = self._sweep(mesh)
@@ -550,16 +549,15 @@ class MigrationPlanner:
             out: dict[str, list[np.ndarray]] = {
                 k: [] for k in ("ids", "src", "dst", "idx", "slot", "src_slot")
             }
+            grown = self._grown_nodes(v_from, v_to, max_new_seg, fields)
             for start in range(0, len(ids), chunk):
                 c = ids[start : start + chunk]
                 base = np.arange(start, start + len(c), dtype=np.int64)
                 if max_new_seg is not None:
                     with span("planner.prefilter"):
-                        keep = self._candidates(
-                            c, v_from, max_new_seg, host, n_replicas=n_replicas
+                        c, base = self._prefilter(
+                            c, base, v_to, grown, host, n_replicas
                         )
-                        self._note_prefilter(len(keep), int(keep.sum()))
-                        c, base = c[keep], base[keep]
                 if c.size == 0:
                     continue
                 if host:
@@ -626,32 +624,103 @@ class MigrationPlanner:
             fields.update(_plan_fields(plan))
         return plan
 
+    def _prefilter(self, c, base, v_to, grown, host, n_replicas):
+        """(ids, positions) of a chunk that reach the diff; counts them."""
+        n = len(c)
+        if grown is not None:
+            keep = self._candidates(c, v_to, grown, host, n_replicas)
+            c, base = c[keep], base[keep]
+        self._note_prefilter(n, len(c))
+        return c, base
+
+    def _grown_nodes(self, v_from, v_to, max_new_seg, fields):
+        """The owner filter's key: the nodes that own, under ``v_to``, a
+        segment the change created or lengthened -- or None when the change
+        also shrank or reassigned a held segment (not a pure addition, so
+        the filter keeps every id), and None with no ``max_new_seg``.
+        Records the filter taken in the plan's span event ``fields``.
+        ``max_new_seg`` is cross-checked: a grown segment past it means the
+        caller's addition claim is wrong."""
+        if max_new_seg is None:
+            return None
+        if getattr(self.engine, "hierarchical", False):
+            raise ValueError(
+                "the owner prefilter is flat-table semantics; "
+                "hierarchical plans scan the full id set (max_new_seg=None)"
+            )
+        a = self.engine.artifact_for(v_from)
+        b = self.engine.artifact_for(v_to)
+        n = max(a.n_segs, b.n_segs)
+        len_a = np.zeros(n, dtype=np.int64)
+        len_b = np.zeros(n, dtype=np.int64)
+        len_a[: a.n_segs] = a.len32
+        len_b[: b.n_segs] = b.len32
+        held = np.nonzero(len_a > 0)[0]
+        # a held segment past v_to's table reads length 0 there: shrunk
+        if (len_b[held] < len_a[held]).any() or (
+            b.node_of[held] != a.node_of[held]
+        ).any():
+            fields.update(filter="fallback", grown_nodes=0)
+            return None
+        grown = np.nonzero(len_b > len_a)[0]
+        if grown.size and int(grown.max()) > max_new_seg:
+            raise ValueError(
+                f"max_new_seg={max_new_seg}, but the change grew segment "
+                f"{int(grown.max())}"
+            )
+        nodes = np.unique(b.node_of[grown])
+        fields.update(filter="owner", grown_nodes=len(nodes))
+        return nodes
+
     def _candidates(
         self,
         chunk: np.ndarray,
-        v_from: int,
-        max_new_seg: int,
+        v_to: int,
+        grown: np.ndarray,
         host: bool,
         n_replicas: int = 1,
     ) -> np.ndarray:
-        """AN <= max_new_seg prefilter mask (sound: unknown -> candidate);
-        the ADDITION NUMBER is computed for the R-replica trace."""
-        if host:
-            from repro.core.asura import addition_numbers_batch
+        """Owner-filter mask: an id is kept iff its ``v_to`` replica set
+        holds a grown node, or a -1 (non-converged, so unknown -- kept).
 
-            art = self.engine.artifact_for(v_from)
-            lengths = art.len32.astype(np.float64) / 2.0**32  # exact round-trip
-            an = addition_numbers_batch(
-                chunk,
-                lengths,
-                art.node_of,
-                n_replicas,
-                params=self.engine.params,
-            )
-            return an <= max_new_seg
-        an = np.asarray(
-            self.engine.addition_numbers_device(
-                chunk, version=v_from, n_replicas=n_replicas
-            )
-        )
-        return (an < 0) | (an <= max_new_seg)
+        Exact for a pure addition (DESIGN.md section 8.1): both versions
+        draw alike up to the first draw that lands in grown mass, and that
+        draw puts a grown node in the ``v_to`` set.  Device backends copy
+        back only the (n,) mask."""
+        e = self.engine
+        if host:
+            if n_replicas == 1:
+                # the bounded loop keeps -1 on non-converged lanes, which
+                # ``place_nodes_at`` would tail-resolve
+                from repro.core.asura import place_batch_u32
+
+                art = e.artifact_for(v_to)
+                segs = place_batch_u32(chunk, art.len32, art.top_level, e.params)
+                nodes = np.where(segs < 0, -1, art.node_of[segs])[:, None]
+            else:
+                nodes = e.place_replica_nodes_at(chunk, v_to, n_replicas)
+            return (nodes < 0).any(axis=1) | np.isin(nodes, grown).any(axis=1)
+        padded, n = self._pad_pow2(chunk, 1)
+        nodes = e.place_replica_nodes_device_at(padded, v_to, n_replicas)
+        # -1 pads the grown list to a pow2 length (one compile per bucket);
+        # a -1 lane is kept anyway
+        g = np.full(1 << max(0, len(grown) - 1).bit_length(), -1, np.int32)
+        g[: len(grown)] = grown
+        return np.asarray(_holds_any_jit()(nodes, g))[:n]
+
+
+def _holds_any_jit():
+    """The owner filter's device mask: ``(nodes (n, R), grown (G,)) -> (n,)
+    bool``, true where a row holds a -1 or one of ``grown``.  Its program is
+    ``jit_holds_any`` in a profiler trace."""
+    fn = _MASK_CACHE.get("holds")
+    if fn is None:
+        import jax
+
+        @jax.jit
+        def holds_any(nodes, grown):
+            hit = nodes[:, :, None] == grown[None, None, :]
+            return (nodes < 0).any(axis=1) | hit.any(axis=(1, 2))
+
+        fn = _MASK_CACHE["holds"] = holds_any
+    return fn

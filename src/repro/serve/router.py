@@ -240,8 +240,8 @@ class ReplicaRouter:
         budgets while ``route_migrating`` keeps every request on a replica
         whose cache is actually warm: the v owner until the session's
         re-prefill lands, the v+1 owner after.  The add-node case uses the
-        ADDITION-NUMBER device prefilter, so only AN-candidates pay the
-        dual-version diff.  With ``n_replicas > 1`` the plan is the
+        planner's owner prefilter, so only sessions the new node takes
+        pay the dual-version diff.  With ``n_replicas > 1`` the plan is the
         per-slot REPLICA plan (DESIGN.md section 10) -- warm-standby
         session caches (section 5.A fan-out) migrate replica by replica,
         and ``route_replicas_migrating`` serves the mixed-version sets.

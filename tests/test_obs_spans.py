@@ -115,8 +115,9 @@ def test_ring_events_unchanged_by_the_annotations(traced):
     assert [e["name"] for e in evs] == ["planner.plan_replicas"]
     [ev] = evs
     plan = traced["plan"]
-    assert list(ev) == ["ts", "kind", "name", "dur_s", "n_scanned", "n_moves",
-                        "v_from", "v_to"]
+    assert list(ev) == ["ts", "kind", "name", "dur_s", "filter", "grown_nodes",
+                        "n_scanned", "n_moves", "v_from", "v_to"]
+    assert (ev["filter"], ev["grown_nodes"]) == ("owner", 1)
     assert ev["n_moves"] == plan.n_moves and ev["n_scanned"] == 4096
     assert (ev["v_from"], ev["v_to"]) == (plan.v_from, plan.v_to)
     assert traced["serve_new_events"] == 0

@@ -17,7 +17,11 @@
   * consumers: the replica coordinator's owner tracking, the failure
     driver's replica repair, the checkpoint store's per-slot live
     add/repair with bit-identical restores every round,
-  * ``remove_numbers_batch`` row-identical to the scalar trace.
+  * ``remove_numbers_batch`` row-identical to the scalar trace,
+  * the owner prefilter on additions: plans bit-identical to the full
+    diff (fractional capacities, a top-level raise, a grown existing
+    node), kept ids exactly the moved ids, the fallback on a shrink, and
+    no ADDITION-NUMBER trace on the path.
 """
 
 import numpy as np
@@ -551,3 +555,139 @@ def test_align_replica_sets_host_vs_device_twin():
         assert np.array_equal(src, s2)
         assert np.array_equal(after, d2)
         assert np.array_equal(src_slot, ss2)
+
+
+# ---------------------------------------------------------------------------
+# Owner prefilter: an add keeps exactly the ids whose v+1 set holds a grown node
+# ---------------------------------------------------------------------------
+
+_PLAN_FIELDS = ("ids", "src", "dst", "index", "slot", "src_slot", "n_scanned")
+
+
+def _owner_cluster(case):
+    """The cluster a case starts from: fractional capacities, or 64 full
+    nodes (one more raises the table's top level)."""
+    from repro.core import make_cluster
+
+    if case == "top_level":  # 64 full segments plus one: top level 5 -> 6
+        return make_uniform_cluster(64)
+    caps = np.random.default_rng(7).uniform(0.5, 2.0, 30)
+    return make_cluster(caps.tolist())
+
+
+def _apply_owner_change(cluster, case):
+    """Grow the cluster -> the ``max_new_seg`` a caller would pass."""
+    if case == "resize":  # an existing node grows: its fractional tail too
+        cluster.resize_node(3, cluster.nodes[3].capacity + 1.7)
+        return len(cluster.seg_lengths()) - 1
+    cap = 1.0 if case == "top_level" else 1.3
+    return max(cluster.add_node(100, cap))
+
+
+@pytest.mark.parametrize("case", ["fractional", "top_level", "resize"])
+@pytest.mark.parametrize("R", [1, 3])
+@pytest.mark.parametrize("backend", ["ref", "numpy"])
+def test_owner_prefilter_plan_equals_full_diff(backend, R, case):
+    cluster = _owner_cluster(case)
+    eng = PlacementEngine(cluster, backend=backend)
+    ids = np.arange(6000, dtype=np.uint32) * np.uint32(2654435761)
+    top_from = eng.artifact().top_level
+    v_from = cluster.version
+    max_new_seg = _apply_owner_change(cluster, case)
+    if case == "top_level":
+        assert eng.artifact().top_level == top_from + 1
+    planner = MigrationPlanner(eng)
+    full = planner.plan_replicas(ids, v_from, cluster.version, R)
+    pre = planner.plan_replicas(
+        ids, v_from, cluster.version, R, chunk=2500, max_new_seg=max_new_seg
+    )
+    assert full.n_moves > 0
+    for field in _PLAN_FIELDS:
+        assert np.array_equal(getattr(full, field), getattr(pre, field)), field
+    if R == 1:  # the single-owner planner takes the same filter
+        one = planner.plan(
+            ids, v_from, cluster.version, chunk=2500, max_new_seg=max_new_seg
+        )
+        for field in ("ids", "src", "dst", "index"):
+            assert np.array_equal(getattr(one, field), getattr(full, field))
+
+
+@pytest.mark.parametrize("backend", ["ref", "numpy"])
+def test_owner_prefilter_keeps_exactly_the_moved_ids(backend):
+    from repro.obs import TraceLedger
+
+    cluster = _owner_cluster("fractional")
+    eng = PlacementEngine(cluster, backend=backend)
+    ids = np.arange(8000, dtype=np.uint32)
+    eng.artifact()
+    v_from = cluster.version
+    max_new_seg = _apply_owner_change(cluster, "fractional")
+    led = TraceLedger(clock=lambda: 0.0)
+    plan = MigrationPlanner(eng, ledger=led).plan_replicas(
+        ids, v_from, cluster.version, 3, chunk=3000, max_new_seg=max_new_seg
+    )
+    assert led.counter("planner.prefilter_scanned") == len(ids)
+    assert led.counter("planner.prefilter_kept") == len(np.unique(plan.ids)) > 0
+    [ev] = led.events("span")
+    assert (ev["filter"], ev["grown_nodes"]) == ("owner", 1)
+
+
+@pytest.mark.parametrize("backend", ["ref", "numpy"])
+def test_owner_prefilter_falls_back_when_a_segment_shrinks(backend):
+    from repro.obs import TraceLedger
+
+    cluster = _owner_cluster("fractional")
+    eng = PlacementEngine(cluster, backend=backend)
+    ids = np.arange(5000, dtype=np.uint32)
+    eng.artifact()
+    v_from = cluster.version
+    cluster.resize_node(4, cluster.nodes[4].capacity - 0.4)  # shrinks
+    max_new_seg = max(cluster.add_node(100, 1.3))
+    led = TraceLedger(clock=lambda: 0.0)
+    planner = MigrationPlanner(eng, ledger=led)
+    pre = planner.plan_replicas(
+        ids, v_from, cluster.version, 3, max_new_seg=max_new_seg
+    )
+    assert led.counter("planner.prefilter_kept") == len(ids)
+    [ev] = led.events("span")
+    assert (ev["filter"], ev["grown_nodes"]) == ("fallback", 0)
+    full = planner.plan_replicas(ids, v_from, cluster.version, 3)
+    assert full.n_moves > 0
+    for field in _PLAN_FIELDS:
+        assert np.array_equal(getattr(full, field), getattr(pre, field)), field
+
+
+@pytest.mark.parametrize("backend", ["ref", "numpy"])
+def test_owner_prefilter_never_runs_the_addition_number_trace(
+    backend, monkeypatch
+):
+    import repro.core.asura as asura
+
+    def refuse(*a, **k):
+        raise AssertionError("the planner ran the ADDITION-NUMBER trace")
+
+    cluster = make_uniform_cluster(8)
+    eng = PlacementEngine(cluster, backend=backend)
+    monkeypatch.setattr(eng, "addition_numbers_device", refuse)
+    monkeypatch.setattr(asura, "addition_numbers_batch", refuse)
+    ids = np.arange(3000, dtype=np.uint32)
+    eng.artifact()
+    v_from = cluster.version
+    max_new_seg = max(cluster.add_node(50, 1.0))
+    plan = MigrationPlanner(eng).plan_replicas(
+        ids, v_from, cluster.version, 3, max_new_seg=max_new_seg
+    )
+    assert plan.n_moves > 0 and (plan.dst == 50).all()
+
+
+def test_owner_prefilter_refuses_a_max_new_seg_below_the_grown_segments():
+    cluster = make_uniform_cluster(8)
+    eng = PlacementEngine(cluster, backend="numpy")
+    eng.artifact()
+    v_from = cluster.version
+    new_segs = cluster.add_node(50, 1.5)
+    with pytest.raises(ValueError, match="grew segment"):
+        MigrationPlanner(eng).plan_replicas(
+            np.arange(100, dtype=np.uint32), v_from, cluster.version, 3,
+            max_new_seg=min(new_segs),
+        )

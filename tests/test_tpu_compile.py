@@ -132,6 +132,17 @@ def test_diff_replicas_fused_ref_compiles(tables):
     _assert_fits(compiled)
 
 
+def test_owner_prefilter_mask_compiles(one_chip):
+    """The planner's owner filter on an add: the (2^20, R) v+1 sets tested
+    against one grown node, copying back only the (2^20,) mask."""
+    from repro.migrate.planner import _holds_any_jit
+
+    compiled = _holds_any_jit().lower(
+        _shape(one_chip, (N_IDS, R), jnp.int32), _shape(one_chip, (1,), jnp.int32)
+    ).compile()
+    _assert_fits(compiled)
+
+
 def test_serving_superstep_compiles(one_chip):
     """The scan-fused serving superstep (generate, route, pow2 select,
     count) at k=8 x 65,536 on a 1,024-node cluster."""
