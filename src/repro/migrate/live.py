@@ -76,36 +76,65 @@ def probe_trace_count(kind: str = "replica_route") -> int:
     return get_ledger().counter(f"migrate.live.{kind}_traces")
 
 
-def _fused_replica_route(statics: tuple):
-    """ONE jit for the whole replica read rule, cached per
-    ``(top_level, s_log2, max_draws, n_replicas)``.
+# Pending ids per row of the two-level probe.  On a TPU v5e, at 65,536
+# lanes and P of 2^17 and 2^19, rows of 128 probed fastest of 32-2,048
+# (4.2-4.8 ms for three slots; 512: 8.7 ms; a binary search: 44-81 ms).
+PROBE_ROW = 128
 
-    The batched serving driver calls ``route_replicas_device`` every
-    batch; dispatching three separate jits (dst placement, membership
-    probe, merge) per batch is measurable overhead and three chances to
-    leak an eager op.  This fuses dst = v+1 replica sets, the per-slot
-    pending probe and the ``where`` merge into one traced body.  The
-    cache key is exactly the static routing configuration -- re-begun
-    windows, rollbacks and fresh ``LiveMigration`` objects at the same
-    config all reuse the same compiled probe (shape changes of the
-    pending view retrace inside jax's own cache, like every probe here).
-    """
-    fn = _ROUTE_CACHE.get(statics)
-    if fn is not None:
-        return fn
+
+def probe_pending(u, ids_pad, src_pad, counts):
+    """The per-slot pending probe, traced: a membership search of ``u`` in
+    each slot of the ``pending_replicas_device`` view, vmapped over the
+    static R slots.
+
+    ``u`` (batch,) uint32 ids -> ``(hit, src)``, each (batch, R): whether
+    slot r of the id still awaits its copy, and the aligned v-side source
+    (meaningful only where ``hit``).  Every traced read rule calls this
+    one body.
+
+    Two levels instead of a binary search: each slot's sorted P ids are
+    viewed as rows of ``PROBE_ROW``; a lane counts the live rows whose
+    first id is <= its id (a compare against every row head, VPU work),
+    then gathers that one row and compares across it.  On a TPU a binary
+    search is ``log2 P`` dependent gathers of single words, each a pass
+    over the batch; this is one row gather.  Ids within a slot are
+    unique, so the live entries equal to an id sit in one row; the
+    sentinel tail is never live (``pos < n``)."""
     import jax
+    import jax.numpy as jnp
+
+    P = ids_pad.shape[1]
+    width = min(PROBE_ROW, P)
+    n_rows = P // width
+
+    def per_slot(sorted_pad, src_vals, n):
+        rows = sorted_pad.reshape(n_rows, width)
+        live = jnp.arange(n_rows, dtype=jnp.int32) * width < n
+        heads = (rows[:, 0][None, :] <= u[:, None]) & live[None, :]
+        row = jnp.maximum(jnp.sum(heads, axis=1, dtype=jnp.int32) - 1, 0)
+        eq = rows[row] == u[:, None]
+        pos = row * width + jnp.argmax(eq, axis=1).astype(jnp.int32)
+        hit = eq.any(axis=1) & (pos < n)
+        return hit, src_vals[pos]
+
+    hit, src = jax.vmap(per_slot)(ids_pad, src_pad, counts)
+    return hit.T, src.T
+
+
+def migrating_owners(statics: tuple):
+    """The traced per-slot read rule of DESIGN.md section 10.2 for one
+    static routing configuration ``(top_level, s_log2, max_draws,
+    n_replicas)``: ``owners(u, len32, node_of, ids_pad, src_pad, counts)``
+    -> (batch, R) int32 holders -- the v+1 replica sets (``len32`` /
+    ``node_of`` are ``v_to``'s device tables), with each pending slot
+    replaced by its v-side source."""
     import jax.numpy as jnp
 
     from repro.kernels.ops import _place_replicas_fused_ref
 
     top_level, s_log2, max_draws, n_replicas = statics
 
-    from repro.obs import get_ledger
-
-    @jax.jit
-    def route(ids, len32, node_of, ids_pad, src_pad, counts):
-        get_ledger().incr("migrate.live.replica_route_traces")  # per TRACE
-        u = ids.astype(jnp.uint32)
+    def owners(u, len32, node_of, ids_pad, src_pad, counts):
         dst = _place_replicas_fused_ref(
             u,
             len32,
@@ -116,41 +145,43 @@ def _fused_replica_route(statics: tuple):
             n_replicas=n_replicas,
             emit_nodes=True,
         )
+        hit, src = probe_pending(u, ids_pad, src_pad, counts)
+        return jnp.where(hit, src, dst)
 
-        def per_slot(sorted_pad, src_vals, n):
-            pos = jnp.searchsorted(sorted_pad, u, side="left")
-            pos_c = jnp.minimum(pos, sorted_pad.shape[0] - 1)
-            hit = (pos < n) & (sorted_pad[pos_c] == u)
-            return hit, src_vals[pos_c]
-
-        hit, src = jax.vmap(per_slot)(ids_pad, src_pad, counts)
-        return jnp.where(hit.T, src.T, dst)
-
-    _ROUTE_CACHE[statics] = route
-    return route
+    return owners
 
 
-@functools.cache
-def _replica_member_fn():
-    """Jitted per-slot membership + aligned-source gather: one vmapped
-    sorted probe over the static R slots of the pending view."""
+def _fused_replica_route(statics: tuple):
+    """ONE jit for the whole replica read rule, cached per
+    ``(top_level, s_log2, max_draws, n_replicas)``.
+
+    The batched serving driver calls ``route_replicas_device`` every
+    batch; dispatching three separate jits (dst placement, membership
+    probe, merge) per batch is measurable overhead and three chances to
+    leak an eager op.  This fuses dst = v+1 replica sets, the per-slot
+    pending probe and the ``where`` merge (``migrating_owners``) into one
+    traced body.  The cache key is exactly the static routing
+    configuration -- re-begun windows, rollbacks and fresh
+    ``LiveMigration`` objects at the same config all reuse the same
+    compiled probe.
+    """
+    fn = _ROUTE_CACHE.get(statics)
+    if fn is not None:
+        return fn
     import jax
     import jax.numpy as jnp
 
+    from repro.obs import get_ledger
+
+    owners = migrating_owners(statics)
+
     @jax.jit
-    def member(ids, ids_pad, src_pad, counts):
-        u = ids.astype(jnp.uint32)
+    def route(ids, len32, node_of, ids_pad, src_pad, counts):
+        get_ledger().incr("migrate.live.replica_route_traces")  # per TRACE
+        return owners(ids.astype(jnp.uint32), len32, node_of, ids_pad, src_pad, counts)
 
-        def per_slot(sorted_pad, src_vals, n):
-            pos = jnp.searchsorted(sorted_pad, u, side="left")
-            pos_c = jnp.minimum(pos, sorted_pad.shape[0] - 1)
-            hit = (pos < n) & (sorted_pad[pos_c] == u)
-            return hit, src_vals[pos_c]
-
-        hit, src = jax.vmap(per_slot)(ids_pad, src_pad, counts)
-        return hit.T, src.T  # (batch, R)
-
-    return member
+    _ROUTE_CACHE[statics] = route
+    return route
 
 
 class LiveMigration(DrainDriver):
@@ -275,6 +306,18 @@ class LiveMigration(DrainDriver):
         pending, src = self.state.pending_replicas(ids)
         return np.where(pending, src, owner)
 
+    def route_operands(self):
+        """``(statics, operands)`` of the traced read rule
+        (``migrating_owners``): the static routing configuration, and
+        ``v_to``'s device tables followed by the per-slot pending view
+        (refreshed here after a round; call outside any transfer guard)."""
+        self._check_live()
+        art = self.engine._device_artifact_for(self.v_to, "asura")
+        params = self.engine.params
+        statics = (art.top_level, params.s_log2, params.max_draws, self.n_replicas)
+        ids_pad, src_pad, counts = self.state.pending_replicas_device()
+        return statics, (art.len32_dev, art.node_of_dev, ids_pad, src_pad, counts)
+
     def route_replicas_device(self, datum_ids):
         """Device-resident ``route_replicas``: (batch, R) int32, zero host
         syncs after the per-round control-path refresh (the per-slot
@@ -284,17 +327,10 @@ class LiveMigration(DrainDriver):
         merge -- runs as ONE cached jit (``_fused_replica_route``), so the
         batched serving driver pays a single dispatch per batch and
         repeated batches never retrace (``probe_trace_count`` tripwire)."""
-        self._check_live()
         import jax.numpy as jnp
 
-        art = self.engine._device_artifact_for(self.v_to, "asura")
-        params = self.engine.params
-        statics = (art.top_level, params.s_log2, params.max_draws, self.n_replicas)
-        ids_pad, src_pad, counts = self.state.pending_replicas_device()
-        return _fused_replica_route(statics)(
-            jnp.asarray(datum_ids), art.len32_dev, art.node_of_dev,
-            ids_pad, src_pad, counts,
-        )
+        statics, operands = self.route_operands()
+        return _fused_replica_route(statics)(jnp.asarray(datum_ids), *operands)
 
     # -- drain control (round/pump/run from the shared DrainDriver loop) ------
 

@@ -174,7 +174,8 @@ class MigrationState:
     (padding to the next power of two bounds recompiles at O(log n)
     distinct shapes); ``pending_replicas_device()`` is the per-slot twin:
     one sorted (ids, src) pair per replica slot, stacked (R, P), so the
-    replica read rule probes all R slots in one jitted vmap.
+    replica read rule probes all R slots in one jitted vmap.  Its P is
+    fixed for the state's life, so a whole drain serves at one shape.
     """
 
     _SENTINEL = np.uint32(0xFFFFFFFF)
@@ -186,6 +187,7 @@ class MigrationState:
         self._dev_view = None  # (padded sorted pending ids, count) device pair
         self._slot_host = None  # per-slot (sorted ids, src) host cache
         self._slot_dev = None  # per-slot device view (ids, src, counts)
+        self._slot_order = None  # per-slot plan rows in id order (plan-constant)
 
     # -- host views ----------------------------------------------------------
 
@@ -228,6 +230,17 @@ class MigrationState:
 
     # -- per-slot views (replica read rule) ------------------------------------
 
+    def _slot_rows(self) -> list[np.ndarray]:
+        """Per slot, the plan's rows of that slot sorted by id: fixed for
+        the plan, so a round's refresh filters them and never sorts."""
+        if self._slot_order is None:
+            plan = self.plan
+            self._slot_order = []
+            for r in range(plan.n_replicas):
+                rows = np.nonzero(plan.slot == r)[0]
+                self._slot_order.append(rows[np.argsort(plan.ids[rows], kind="stable")])
+        return self._slot_order
+
     def _slot_tables(self) -> list[tuple[np.ndarray, np.ndarray]]:
         """Per-slot sorted pending ``(ids, src)`` pairs, cached per round.
 
@@ -237,12 +250,9 @@ class MigrationState:
         if self._slot_host is None:
             plan = self.plan
             tables = []
-            for r in range(plan.n_replicas):
-                mask = ~self.landed & (plan.slot == r)
-                ids = plan.ids[mask]
-                src = plan.src[mask]
-                order = np.argsort(ids, kind="stable")
-                tables.append((ids[order], src[order]))
+            for rows in self._slot_rows():
+                rows = rows[~self.landed[rows]]
+                tables.append((plan.ids[rows], plan.src[rows]))
             self._slot_host = tables
         return self._slot_host
 
@@ -271,30 +281,40 @@ class MigrationState:
 
         ``ids_pad`` (R, P) sorted sentinel-padded pending ids per slot,
         ``src_pad`` (R, P) their aligned v-side sources, ``counts`` (R,)
-        live lengths.  P is the shared next power of two, so recompiles
-        stay O(log n) and the replica read rule vmaps one probe over the
-        static R slots.  Rebuilt lazily after ``mark_landed`` -- one upload
-        per round on the control path; call outside any transfer guard.
+        live lengths.  P is the next power of two of the plan's largest
+        per-slot row count, fixed for this state's life: as rows land the
+        sentinel tail grows and the shape does not, so serving through a
+        whole drain compiles the read rule once.  Rebuilt lazily after
+        ``mark_landed`` -- one host rebuild and upload per round on the
+        control path (span ``migrate.pending_refresh``; ledger counters
+        ``migrate.pending_refreshes`` and ``migrate.pending_rows``, the
+        live rows summed over the slots); call outside any transfer guard.
         """
         if self._slot_dev is None:
             import jax.numpy as jnp
 
-            tables = self._slot_tables()
-            n_max = max((len(t[0]) for t in tables), default=0)
-            padded_len = max(1, 1 << (n_max - 1).bit_length()) if n_max else 1
-            R = self.plan.n_replicas
-            ids_pad = np.full((R, padded_len), self._SENTINEL, dtype=np.uint32)
-            src_pad = np.full((R, padded_len), -1, dtype=np.int32)
-            counts = np.zeros(R, dtype=np.int32)
-            for r, (p_ids, p_src) in enumerate(tables):
-                ids_pad[r, : len(p_ids)] = p_ids
-                src_pad[r, : len(p_ids)] = p_src
-                counts[r] = len(p_ids)
-            self._slot_dev = (
-                jnp.asarray(ids_pad),
-                jnp.asarray(src_pad),
-                jnp.asarray(counts),
-            )
+            from repro.obs import get_ledger
+
+            with span("migrate.pending_refresh"):
+                rows = self._slot_rows()
+                n_max = max((len(r) for r in rows), default=0)
+                padded_len = 1 << max(0, n_max - 1).bit_length()
+                R = self.plan.n_replicas
+                ids_pad = np.full((R, padded_len), self._SENTINEL, dtype=np.uint32)
+                src_pad = np.full((R, padded_len), -1, dtype=np.int32)
+                counts = np.zeros(R, dtype=np.int32)
+                for r, (p_ids, p_src) in enumerate(self._slot_tables()):
+                    ids_pad[r, : len(p_ids)] = p_ids
+                    src_pad[r, : len(p_ids)] = p_src
+                    counts[r] = len(p_ids)
+                self._slot_dev = (
+                    jnp.asarray(ids_pad),
+                    jnp.asarray(src_pad),
+                    jnp.asarray(counts),
+                )
+            ledger = get_ledger()
+            ledger.incr("migrate.pending_refreshes")
+            ledger.incr("migrate.pending_rows", int(counts.sum()))
         return self._slot_dev
 
     # -- device view ----------------------------------------------------------

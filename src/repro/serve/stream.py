@@ -32,10 +32,12 @@ exact integer psum per batch -- so the sharded stream is bit-identical to
 the single-device stream (selftest-enforced at 8 forced host devices).
 
 External id batches (``route_batch``) reuse the migration planner's pow2
-bucketing so ragged tails share one compile per bucket, and
-``serve_migrating`` drives the stream through a live migration window via
-the cached fused ``route_replicas_device`` probe -- dual-version serving
-keeps working under the batched driver.
+bucketing so ragged tails share one compile per bucket.  Given a live
+migration, ``route_batch`` routes host-fed batches through the window's
+per-slot read rule in one jit (``route_migrating``), and
+``serve_migrating`` drives the generated stream through it via the cached
+fused ``route_replicas_device`` probe -- dual-version serving keeps
+working under the batched driver.
 """
 
 from __future__ import annotations
@@ -663,20 +665,21 @@ class RequestStreamDriver:
 
     # -- external batches (pow2 bucketing -- ragged tails share compiles) -----
 
-    def _route_batch_fn(self, statics: tuple):
+    def _route_batch_fn(self, owners_fn, name: str = "body"):
+        """The jitted external-batch body around ``owners_fn(ids, *tables)
+        -> (batch, R)`` holders: the flat replica placement
+        (``replica_owners_body``) or the live-migration read rule
+        (``migrate.live.migrating_owners``).  ``name`` names the jit, and so
+        the program in a device trace (``jit_body``,
+        ``jit_route_migrating``)."""
         import jax
         import jax.numpy as jnp
 
         R, policy = self.n_replicas, self.policy
         n_bins, max_hist = self.n_bins, self.max_hist
         instrumented = self._instrumented
-        # External batches carry pad lanes, whose kernel stats would be
-        # phantom work -- only the valid-masked routed/served metrics
-        # accumulate here, so the body routes without emit_stats.
-        owners_fn = replica_owners_body(statics, R)
         driver = self
 
-        @jax.jit
         def body(ids, n_valid, key, step_idx, counts, queue, qhist, *rest):
             driver.ledger.incr("serve.step_traces")
             if instrumented:
@@ -705,16 +708,25 @@ class RequestStreamDriver:
                 return counts, queue, qhist, slab + delta, step_idx + 1, chosen
             return counts, queue, qhist, step_idx + 1, chosen
 
-        return body
+        body.__name__ = body.__qualname__ = name
+        return jax.jit(body)
 
-    def route_batch(self, datum_ids):
+    def route_batch(self, datum_ids, migration=None):
         """Serve one EXTERNAL id batch through the fused select+count pass
         -> (len(ids),) int32 chosen nodes (device array).
 
         Ids are pow2-bucketed (``migrate.planner.pad_pow2``) with the valid
         count traced, so ragged tails share one compile per bucket and pad
         lanes never touch a counter.  Single-device (the generated stream
-        is the mesh path)."""
+        is the mesh path).
+
+        With a live ``migration`` (a ``LiveMigration`` of the driver's R)
+        the batch routes through the window's per-slot read rule (DESIGN.md
+        section 10.2): slot r goes to its v-side source while its row is
+        pending and to the v+1 set's slot r otherwise, then the same select
+        and count -- one jit, ``route_migrating``, whose shape stays fixed
+        through the whole drain.  ``None`` or a finished migration routes
+        at the cluster's current version, as without one."""
         import jax.numpy as jnp
 
         from repro.kernels.ops import _head
@@ -724,12 +736,30 @@ class RequestStreamDriver:
                 "route_batch serves host-fed batches single-device; "
                 "mesh-sharded serving goes through step()"
             )
+        live = migration is not None and not migration.done
+        if live:
+            self._check_window(migration)
         with span("serve.route_batch"):
             ids = jnp.asarray(datum_ids)
             n = int(ids.shape[0])
             padded, n_valid = pad_pow2(ids)
-            tables, statics = route_statics(self.engine, self.algorithm)
-            fn = self._cached(("route_batch", statics), lambda: self._route_batch_fn(statics))
+            if live:
+                from repro.migrate.live import migrating_owners
+
+                statics, tables = migration.route_operands()
+                fn = self._cached(
+                    ("route_migrating", statics),
+                    lambda: self._route_batch_fn(migrating_owners(statics), "route_migrating"),
+                )
+            else:
+                # External batches carry pad lanes, whose kernel stats would
+                # be phantom work -- only the valid-masked routed/served
+                # metrics accumulate, so the body routes without emit_stats.
+                tables, statics = route_statics(self.engine, self.algorithm)
+                fn = self._cached(
+                    ("route_batch", statics),
+                    lambda: self._route_batch_fn(replica_owners_body(statics, self.n_replicas)),
+                )
             if self._instrumented:
                 (self.counts, self.queue, self.qhist, slab, self._step,
                  chosen) = fn(
@@ -745,6 +775,19 @@ class RequestStreamDriver:
                 )
             self.steps_done += 1
             return _head(chosen, n)
+
+    def _check_window(self, migration) -> None:
+        """A migration window serves single-device, at the driver's R."""
+        if self._sweep is not None:
+            raise ValueError(
+                "migration windows are single-device (the pending views "
+                "refresh per round); build the driver without mesh="
+            )
+        if migration.n_replicas != self.n_replicas:
+            raise ValueError(
+                f"driver serves R={self.n_replicas} but the migration plan "
+                f"is R={migration.n_replicas}"
+            )
 
     # -- serving through a live migration window ------------------------------
 
@@ -806,16 +849,7 @@ class RequestStreamDriver:
         datum mid-drain.  Three jitted dispatches (generate, route,
         select+count), zero host syncs after the per-round pending-view
         refresh.  Single-device, like the window itself."""
-        if self._sweep is not None:
-            raise ValueError(
-                "migration windows are single-device (the pending views "
-                "refresh per round); build the driver without mesh="
-            )
-        if migration.n_replicas != self.n_replicas:
-            raise ValueError(
-                f"driver serves R={self.n_replicas} but the migration plan "
-                f"is R={migration.n_replicas}"
-            )
+        self._check_window(migration)
         gen = self._cached(("gen",), self._gen_fn)
         ids, sel = gen(self._key, self._step, self.traffic.thresholds_dev)
         owners = migration.route_replicas_device(ids)
@@ -838,47 +872,30 @@ class RequestStreamDriver:
     def _mig_superstep_fn(self, statics: tuple, k: int):
         """K migration-window batches in ONE jit: generate, the fused
         dual-version replica read rule (the ``migrate.live``
-        ``_fused_replica_route`` body, inlined) and select+count, scanned
-        with the serving state as the carry -- the superstep twin of
+        ``migrating_owners`` body) and select+count, scanned with the
+        serving state as the carry -- the superstep twin of
         ``serve_migrating``'s three dispatches."""
         import jax
         import jax.numpy as jnp
 
-        from repro.kernels.ops import _place_replicas_fused_ref
+        from repro.migrate.live import migrating_owners
 
-        top_level, s_log2, max_draws, R = statics
+        R = statics[3]
         batch, id_salt = self.batch, self.traffic.id_salt
         policy, n_bins, max_hist = self.policy, self.n_bins, self.max_hist
         instrumented = self._instrumented
+        owners_fn = migrating_owners(statics)
         driver = self
 
         @jax.jit
         def super_body(key, step_idx, counts, queue, qhist, *rest):
             driver.ledger.incr("serve.superstep_traces")  # per TRACE only
             if instrumented:
-                (slab, service, thresholds, len32, node_of,
-                 ids_pad, src_pad, pcounts) = rest
+                slab, service, thresholds, *tables = rest
                 carry0 = (counts, queue, qhist, slab, step_idx)
             else:
-                (service, thresholds, len32, node_of,
-                 ids_pad, src_pad, pcounts) = rest
+                service, thresholds, *tables = rest
                 carry0 = (counts, queue, qhist, step_idx)
-
-            def route(u):
-                dst = _place_replicas_fused_ref(
-                    u, len32, node_of,
-                    top_level=top_level, s_log2=s_log2, max_draws=max_draws,
-                    n_replicas=R, emit_nodes=True,
-                )
-
-                def per_slot(sorted_pad, src_vals, n):
-                    pos = jnp.searchsorted(sorted_pad, u, side="left")
-                    pos_c = jnp.minimum(pos, sorted_pad.shape[0] - 1)
-                    hit = (pos < n) & (sorted_pad[pos_c] == u)
-                    return hit, src_vals[pos_c]
-
-                hit, src = jax.vmap(per_slot)(ids_pad, src_pad, pcounts)
-                return jnp.where(hit.T, src.T, dst)
 
             def sub(carry, _):
                 if instrumented:
@@ -887,7 +904,7 @@ class RequestStreamDriver:
                     c, q, qh, si = carry
                 lanes = jnp.arange(batch, dtype=jnp.uint32)
                 ids, sel = TrafficModel.draw(key, si, lanes, thresholds, id_salt)
-                owners = route(ids.astype(jnp.uint32))
+                owners = owners_fn(ids.astype(jnp.uint32), *tables)
                 chosen = select_replica(
                     owners, sel, c, policy=policy, n_replicas=R
                 )
@@ -920,34 +937,16 @@ class RequestStreamDriver:
         inside the scan, counters stay fresh between sub-batches, and the
         pending snapshot is the one at call time (refresh per round, as
         with ``serve_migrating``).  Single-device, like the window."""
-        if self._sweep is not None:
-            raise ValueError(
-                "migration windows are single-device (the pending views "
-                "refresh per round); build the driver without mesh="
-            )
-        if migration.n_replicas != self.n_replicas:
-            raise ValueError(
-                f"driver serves R={self.n_replicas} but the migration plan "
-                f"is R={migration.n_replicas}"
-            )
+        self._check_window(migration)
         k = int(k)
         if k < 1:
             raise ValueError(f"superstep needs k >= 1, got {k}")
-        migration._check_live()
-        art = migration.engine._device_artifact_for(migration.v_to, "asura")
-        params = migration.engine.params
-        statics = (
-            art.top_level, params.s_log2, params.max_draws, self.n_replicas
-        )
-        ids_pad, src_pad, pcounts = migration.state.pending_replicas_device()
+        statics, tables = migration.route_operands()
         fn = self._cached(
             ("mig_superstep", statics, k),
             lambda: self._mig_superstep_fn(statics, k),
         )
-        operands = (
-            self._service, self.traffic.thresholds_dev,
-            art.len32_dev, art.node_of_dev, ids_pad, src_pad, pcounts,
-        )
+        operands = (self._service, self.traffic.thresholds_dev, *tables)
         if self._instrumented:
             (self.counts, self.queue, self.qhist, slab, self._step,
              ids, chosen) = fn(

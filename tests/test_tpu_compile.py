@@ -165,6 +165,34 @@ def test_serving_superstep_compiles(one_chip):
     _assert_fits(compiled)
 
 
+def test_route_migrating_compiles(one_chip):
+    """``route_batch`` through a live migration (``route_migrating``): the
+    v+1 ladder, the per-slot pending probe over a (3, 2^19) view (a rack
+    leaving puts nearly every row in one slot), pow2 select and count, on
+    65,536 host-fed keys."""
+    from repro.core import PlacementEngine, make_uniform_cluster
+    from repro.migrate.live import migrating_owners
+    from repro.serve import RequestStreamDriver
+    from repro.serve.stream import route_statics
+
+    engine = PlacementEngine(make_uniform_cluster(TABLE), backend="ref")
+    driver = RequestStreamDriver(engine, batch=SERVE_BATCH, n_keys=1, law="uniform",
+                                 n_replicas=R, policy="pow2")
+    (len32, node_of), (_, top, s_log2, max_draws) = route_statics(engine)
+    pad = 1 << 19
+    args = (
+        jnp.zeros(SERVE_BATCH, jnp.uint32), jnp.uint32(0), driver._key, driver._step,
+        driver.counts, driver.queue, driver.qhist, driver._service, len32, node_of,
+        jnp.zeros((R, pad), jnp.uint32), jnp.zeros((R, pad), jnp.int32),
+        jnp.zeros(R, jnp.int32),
+    )
+    shapes = [_shape(one_chip, a.shape, a.dtype) for a in args]
+    fn = driver._route_batch_fn(migrating_owners((top, s_log2, max_draws, R)), "route_migrating")
+    lowered = fn.lower(*shapes)
+    assert "jit_route_migrating" in lowered.as_text().splitlines()[0]
+    _assert_fits(lowered.compile())
+
+
 def test_mover_round_block_compiles(one_chip):
     """The throttled mover's k-round admission scan over a 65,536-row plan
     on a 1,025-node cluster."""
