@@ -50,8 +50,10 @@ from .asura_place import (
 from .hierarchy import hier_place_replicas_pallas, hier_place_replicas_ref
 from .ref import (
     addition_numbers_ref,
+    depth_histogram,
+    draws_histogram,
     place_ref,
-    place_replicas_ref,
+    replica_ladder,
     resolve_tail_dev,
 )
 
@@ -186,41 +188,36 @@ def _place_replicas_fused_ref(
     emit_nodes: bool,
     emit_stats: bool = False,
 ):
-    """jnp-reference replica placement with the optional fused node gather
-    (one jit so no eager scalar ops escape to the host between calls).
+    """jnp-reference replica placement (one jit so no eager scalar ops
+    escape to the host between calls).  ``emit_nodes=True`` returns the
+    node ids the ladder already carries, with no gather of its own.
 
     ``emit_stats=True`` returns ``(out, stats)`` where ``stats`` is the
-    (DEPTH_BINS + 1,) uint32 vector ``[ladder_depth_hist..., nonconverged]``
-    the obs device plane accumulates into its slab -- placements stay
-    bit-identical (tested)."""
+    (DEPTH_BINS + 1 + DRAW_BINS,) uint32 vector ``[ladder_depth_hist...,
+    nonconverged, ladder_draws_hist...]`` the obs device plane accumulates
+    into its slab -- placements stay bit-identical (tested)."""
+    segs, nodes, counters = replica_ladder(
+        ids,
+        len32,
+        node_of,
+        top_level=top_level,
+        s_log2=s_log2,
+        max_draws=max_draws,
+        n_replicas=n_replicas,
+        emit_stats=emit_stats,
+    )
+    out = nodes.T if emit_nodes else segs.T
     if emit_stats:
-        segs, depth_hist = place_replicas_ref(
-            ids,
-            len32,
-            node_of,
-            top_level=top_level,
-            s_log2=s_log2,
-            max_draws=max_draws,
-            n_replicas=n_replicas,
-            emit_stats=True,
-        )
         nonconv = jnp.sum((segs < 0).astype(jnp.uint32))
-        stats = jnp.concatenate([depth_hist, nonconv[None]])
-    else:
-        segs = place_replicas_ref(
-            ids,
-            len32,
-            node_of,
-            top_level=top_level,
-            s_log2=s_log2,
-            max_draws=max_draws,
-            n_replicas=n_replicas,
+        stats = jnp.concatenate(
+            [
+                depth_histogram(counters, top_level),
+                nonconv[None],
+                draws_histogram(counters),
+            ]
         )
-    if emit_nodes:
-        segs = jnp.where(segs >= 0, jnp.take(node_of, jnp.maximum(segs, 0)), -1)
-    if emit_stats:
-        return segs, stats
-    return segs
+        return out, stats
+    return out
 
 
 def _default_interpret(interpret: bool | None) -> bool:

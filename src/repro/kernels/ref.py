@@ -20,6 +20,10 @@ KMULT = 0x85EBCA77
 # construction bounds top_level <= 32 - s_log2, so 34 bins (clipped)
 # cover every reachable depth at any parameterization.
 DEPTH_BINS = 34
+# Ladder-draws histogram width (obs device plane): bin d counts the lanes
+# that needed d draws to find their replicas; the last bin holds every
+# lane that needed DRAW_BINS - 1 or more.
+DRAW_BINS = 64
 # NOTE: no module-level jnp constants here -- this module's helpers run
 # inside Pallas kernels, which reject captured device arrays.
 
@@ -210,6 +214,38 @@ def resolve_tail_dev(
     return jnp.where(miss, tail_seg, segs)
 
 
+# Row width of ``_prefix_count``'s blocked scan.
+_SCAN_ROW = 1024
+
+
+def _prefix_count(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a 1-D int32 array, as a blocked scan: rows
+    of ``_SCAN_ROW``, then the row totals, recursively.  One ``cumsum``
+    over the whole array lowers on the TPU to a reduce-window as wide as
+    the array, whose compile time grows with it (~20 s at 2^20 lanes,
+    against ~2 s blocked)."""
+    n = x.shape[0]
+    if n <= _SCAN_ROW:
+        return jnp.cumsum(x)
+    rows = jnp.cumsum(jnp.pad(x, (0, -n % _SCAN_ROW)).reshape(-1, _SCAN_ROW), axis=1)
+    before = _prefix_count(rows[:, -1]) - rows[:, -1]
+    return (rows + before[:, None]).reshape(-1)[:n]
+
+
+def _live_slots(live: jax.Array, w: int) -> jax.Array:
+    """(w,) int32 lane indices of the live lanes, in lane order (prefix
+    count scatter).  Slots past the live count hold lane 0: a duplicate
+    lane recomputes lane 0's exact draws.  Live lanes past ``w`` are
+    dropped, so callers compact only once the live lanes fit."""
+    pos = _prefix_count(live.astype(jnp.int32)) - 1
+    slot = jnp.where(live, pos, w)  # settled lanes -> out of range, dropped
+    return (
+        jnp.zeros((w,), dtype=jnp.int32)
+        .at[slot]
+        .set(jnp.arange(live.shape[0], dtype=jnp.int32), mode="drop")
+    )
+
+
 # Full-width draws before the bulk place loop compacts its stragglers
 # (below).  After p draws a lane survives with probability ~(1-fill)^p,
 # so 4 leaves ~6% of lanes at the half-full tables every post-add
@@ -285,16 +321,8 @@ def place_ref(
 
     def narrow(state):
         i, counters, result, done = state
-        live = ~done
-        pos = jnp.cumsum(live.astype(jnp.int32)) - 1
-        slot = jnp.where(live, pos, w)  # dead lanes -> OOB, dropped
-        idx = (
-            jnp.zeros((w,), dtype=jnp.int32)
-            .at[slot]
-            .set(jnp.arange(batch, dtype=jnp.int32), mode="drop")
-        )
-        # unused slots hold lane 0: duplicates recompute lane 0's exact
-        # draw sequence, so the write-back scatter is value-unique
+        idx = _live_slots(~done, w)
+        # duplicates of lane 0 write lane 0's own result: value-unique
         sub = (i, counters[:, idx], result[idx], done[idx])
         _, _, sub_result, _ = jax.lax.while_loop(cond, mk_body(ids[idx]), sub)
         return result.at[idx].set(sub_result)
@@ -372,6 +400,160 @@ def addition_numbers_ref(
     return jnp.where((found >= R) & (min_k != NO_K), min_k, jnp.int32(-1))
 
 
+def _u32(x: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(x, jnp.uint32)
+
+
+def _i32(x: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def replica_ladder(
+    ids: jax.Array,
+    len32: jax.Array,
+    node_of: jax.Array,
+    *,
+    top_level: int,
+    s_log2: int,
+    max_draws: int,
+    n_replicas: int,
+    emit_stats: bool = False,
+):
+    """The section 5.A replica draw loop -> ``(segs, nodes, counters)``.
+
+    ``segs`` and ``nodes`` are (R, batch) int32 (-1 where a lane did not
+    converge within ``max_draws * R`` draws); ``nodes`` is the carried
+    ``node_of[segs]``, so callers that want node ids read it and gather
+    nothing.  ``counters`` is the (top_level + 1, batch) draw-counter
+    array; with ``emit_stats`` on, satisfied lanes stop ticking, so row
+    ``r`` counts the draws of depth >= r + 1 each lane issued while still
+    seeking replicas, and row 0 (every draw consults the top level) is the
+    number of draws the lane needed.  Without stats the counters of
+    satisfied lanes keep ticking and mean nothing.
+
+    Straggler block (DESIGN.md section 15.3): per-lane draw counts are
+    geometric, so a lockstep loop keeps drawing -- and gathering both
+    tables -- over the full batch until its slowest lane is done.  The
+    full-width loop runs only while the live lanes (``found < R``)
+    outnumber ``w = batch // 8``; the live lanes are then compacted
+    (``_live_slots``, unused slots repeat lane 0) into a ``w``-wide block
+    that finishes narrow, and the block's results are scattered back.
+    The block starts only once the live lanes fit in it, so it cannot
+    overflow (when the draw cap ends the wide loop first, the block runs
+    no draw and writes back what it read; under 8 lanes the block is
+    empty and the wide loop is the whole ladder).  A lane's draws are a
+    function of its id and its own counters alone, so the results (and,
+    with stats on, the counters) are bit-identical to the plain loop.
+    """
+    ids = ids.astype(jnp.uint32)
+    n_segs = len32.shape[0]
+    batch = ids.shape[0]
+    R = n_replicas
+    cap = max_draws * max(1, R)
+
+    def mk_body(lane_ids):
+        def body(state):
+            i, counters, segs, nodes, found = state
+            # With stats on, satisfied lanes stop ticking their counters:
+            # the lockstep dead draws they keep issuing depend on the
+            # slowest lane sharing their loop, so counting them would make
+            # the derived histograms depend on how a stream is sharded or
+            # compacted.  A frozen lane is inert for placement either way
+            # (``take`` requires ``found < R``).
+            k, f, counters = next_asura(
+                lane_ids,
+                counters,
+                top_level,
+                s_log2,
+                active=(found < R) if emit_stats else None,
+            )
+            k_safe = jnp.minimum(k, n_segs - 1)
+            hit = (found < R) & (k < n_segs) & (f < len32[k_safe])
+            node_k = node_of[k_safe]
+            dup = jnp.zeros(lane_ids.shape, dtype=bool)
+            for r in range(R):
+                dup |= (nodes[r] >= 0) & (nodes[r] == node_k)
+            take = hit & ~dup
+            segs = jnp.stack(
+                [jnp.where(take & (found == r), k, segs[r]) for r in range(R)]
+            )
+            nodes = jnp.stack(
+                [jnp.where(take & (found == r), node_k, nodes[r]) for r in range(R)]
+            )
+            found = found + take.astype(jnp.int32)
+            return i + 1, counters, segs, nodes, found
+
+        return body
+
+    def cond(state):
+        i, found = state[0], state[4]
+        return (i < cap) & ~jnp.all(found >= R)
+
+    state = (
+        0,
+        jnp.zeros((top_level + 1, batch), dtype=jnp.uint32),
+        jnp.full((R, batch), -1, dtype=jnp.int32),
+        jnp.full((R, batch), -1, dtype=jnp.int32),
+        jnp.zeros((batch,), dtype=jnp.int32),
+    )
+    w = batch >> 3
+
+    def wide_cond(state):
+        i, found = state[0], state[4]
+        return (i < cap) & (jnp.sum((found < R).astype(jnp.int32)) > w)
+
+    i, counters, segs, nodes, found = jax.lax.while_loop(
+        wide_cond, mk_body(ids), state
+    )
+    live = found < R
+    n_live = jnp.sum(live.astype(jnp.int32))
+    idx = _live_slots(live, w)
+    # one gather of every lane's whole state, as columns of one stack
+    rows = jnp.concatenate(
+        [ids[None], counters, _u32(segs), _u32(nodes), _u32(found)[None]]
+    )[:, idx]
+    c1 = top_level + 2
+    sub_ids, sub_found = rows[0], _i32(rows[-1])
+    sub = (i, rows[1:c1], _i32(rows[c1 : c1 + R]), _i32(rows[c1 + R : -1]), sub_found)
+    _, sub_ctr, sub_segs, sub_nodes, _ = jax.lax.while_loop(
+        cond, mk_body(sub_ids), sub
+    )
+    # unused slots (lane 0's copies) write nothing back
+    dst = jnp.where(jnp.arange(w, dtype=jnp.int32) < n_live, idx, batch)
+    back = [_u32(segs), _u32(nodes)]
+    sub_back = [_u32(sub_segs), _u32(sub_nodes)]
+    if emit_stats:
+        back.append(counters)
+        sub_back.append(sub_ctr)
+    merged = (
+        jnp.concatenate(back)
+        .at[:, dst]
+        .set(jnp.concatenate(sub_back), mode="drop")
+    )
+    segs, nodes = _i32(merged[:R]), _i32(merged[R : 2 * R])
+    if emit_stats:
+        counters = merged[2 * R :]
+    return segs, nodes, counters
+
+
+def depth_histogram(counters: jax.Array, top_level: int) -> jax.Array:
+    """(DEPTH_BINS,) uint32 consulted-ladder-depth histogram, derived from
+    gated draw counters: row ``r`` ticks once for every draw of depth >=
+    r + 1, so the histogram is the first difference of the row sums."""
+    # cnt[r] = draws of depth >= r + 1; hist[d] = cnt[d-1] - cnt[d]
+    cnt = jnp.sum(counters, axis=1, dtype=jnp.uint32)
+    cnt = jnp.concatenate([cnt, jnp.zeros((1,), dtype=jnp.uint32)])
+    dh = jnp.zeros((DEPTH_BINS,), dtype=jnp.uint32)
+    return dh.at[1 : top_level + 2].set(cnt[:-1] - cnt[1:])
+
+
+def draws_histogram(counters: jax.Array) -> jax.Array:
+    """(DRAW_BINS,) uint32 histogram of the draws each lane needed, from
+    gated draw counters (row 0: every draw consults the top level)."""
+    d = jnp.minimum(counters[0], DRAW_BINS - 1).astype(jnp.int32)
+    return jnp.bincount(d, length=DRAW_BINS).astype(jnp.uint32)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("top_level", "s_log2", "max_draws", "n_replicas", "emit_stats"),
@@ -393,77 +575,33 @@ def place_replicas_ref(
     against the nodes of already-picked replicas, carried in-register so the
     dup test costs no extra table gather).  -1 marks lanes that did not
     converge (the wrapper raises).  Bit-identical to
-    ``repro.core.asura.place_replicas_scalar`` lane-by-lane (tested).
+    ``repro.core.asura.place_replicas_scalar`` lane-by-lane (tested).  The
+    loop itself is ``replica_ladder``.
 
     ``emit_stats=True`` returns ``(segs, depth_hist)`` where ``depth_hist``
     is the (DEPTH_BINS,) uint32 consulted-ladder-depth histogram over every
     draw each lane issued while still seeking replicas -- the obs device
     plane's view of how much ladder work the batch cost.  It is DERIVED
-    from the final draw counters rather than accumulated per draw: row
-    ``r`` of the counter array ticks once for every draw that consulted
-    level ``top_level - r``, i.e. every draw of depth >= r + 1, so the
-    histogram is the first difference of the per-row counter sums -- one
-    reduction after the loop plus a lane-liveness gate folded into the
-    existing one-row counter tick (the <= 1.05x overhead ceiling rules
-    out both an in-loop scatter and a full-array counter select).
-    Satisfied lanes' counters freeze so the histogram is a function of
-    each lane's id alone -- summing per-shard histograms of any partition
-    of a batch is bit-identical to the unsharded histogram (the sharded
-    snapshot merge relies on this).  The placement stream is bit-identical
-    either way (frozen lanes are inert: ``take`` requires ``found < R``).
+    from the final draw counters (``depth_histogram``) rather than
+    accumulated per draw: one reduction after the loop plus a lane-liveness
+    gate folded into the existing one-row counter tick (the <= 1.05x
+    overhead ceiling rules out both an in-loop scatter and a full-array
+    counter select).  Satisfied lanes' counters freeze so the histogram is
+    a function of each lane's id alone -- summing per-shard histograms of
+    any partition of a batch is bit-identical to the unsharded histogram
+    (the sharded snapshot merge relies on this).  The placement stream is
+    bit-identical either way.
     """
-    ids = ids.astype(jnp.uint32)
-    n_segs = len32.shape[0]
-    batch = ids.shape[0]
-    R = n_replicas
-
-    def cond(state):
-        i, found = state[0], state[4]
-        return (i < max_draws * max(1, R)) & ~jnp.all(found >= R)
-
-    def body(state):
-        i, counters, segs, nodes, found = state
-        # With stats on, satisfied lanes stop ticking their counters: the
-        # lockstep dead draws they keep issuing depend on the slowest lane
-        # IN THIS BATCH, so counting them would make the derived histogram
-        # depend on how a stream is sharded.  A frozen lane is inert for
-        # placement either way (``take`` requires ``found < R``), so the
-        # segment stream is bit-identical with or without stats.
-        k, f, counters = next_asura(
-            ids,
-            counters,
-            top_level,
-            s_log2,
-            active=(found < R) if emit_stats else None,
-        )
-        k_safe = jnp.minimum(k, n_segs - 1)
-        hit = (found < R) & (k < n_segs) & (f < len32[k_safe])
-        node_k = node_of[k_safe]
-        dup = jnp.zeros((batch,), dtype=bool)
-        for r in range(R):
-            dup |= (nodes[r] >= 0) & (nodes[r] == node_k)
-        take = hit & ~dup
-        segs = jnp.stack(
-            [jnp.where(take & (found == r), k, segs[r]) for r in range(R)]
-        )
-        nodes = jnp.stack(
-            [jnp.where(take & (found == r), node_k, nodes[r]) for r in range(R)]
-        )
-        found = found + take.astype(jnp.int32)
-        return i + 1, counters, segs, nodes, found
-
-    counters0 = jnp.zeros((top_level + 1, batch), dtype=jnp.uint32)
-    segs0 = jnp.full((R, batch), -1, dtype=jnp.int32)
-    nodes0 = jnp.full((R, batch), -1, dtype=jnp.int32)
-    found0 = jnp.zeros((batch,), dtype=jnp.int32)
-    _, counters, segs, _, _ = jax.lax.while_loop(
-        cond, body, (0, counters0, segs0, nodes0, found0)
+    segs, _, counters = replica_ladder(
+        ids,
+        len32,
+        node_of,
+        top_level=top_level,
+        s_log2=s_log2,
+        max_draws=max_draws,
+        n_replicas=n_replicas,
+        emit_stats=emit_stats,
     )
     if emit_stats:
-        # cnt[r] = draws of depth >= r + 1; hist[d] = cnt[d-1] - cnt[d]
-        cnt = jnp.sum(counters, axis=1, dtype=jnp.uint32)
-        cnt = jnp.concatenate([cnt, jnp.zeros((1,), dtype=jnp.uint32)])
-        dh = jnp.zeros((DEPTH_BINS,), dtype=jnp.uint32)
-        dh = dh.at[1 : top_level + 2].set(cnt[:-1] - cnt[1:])
-        return segs.T, dh
+        return segs.T, depth_histogram(counters, top_level)
     return segs.T

@@ -626,6 +626,7 @@ def selftest(
             shard.step()
         snap_a, snap_b = reg_solo.snapshot(), reg_shard.snapshot()
         assert set(snap_a) == set(snap_b), "metric name sets differ"
+        assert int(snap_a["asura.ladder_draws"].sum()) == 3 * batch
         for name in snap_a:
             assert np.array_equal(snap_a[name], snap_b[name]), (
                 f"R={R}: sharded metric {name!r} differs"

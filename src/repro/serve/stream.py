@@ -90,7 +90,8 @@ def replica_owners_body(statics: tuple, n_replicas: int, emit_stats: bool = Fals
 
     ``emit_stats=True`` returns ``(owners, stats)`` instead, where
     ``stats`` is the algorithm's uint32 device-plane vector (ASURA:
-    ``[ladder_depth_hist..., nonconverged]`` of length ``DEPTH_BINS + 1``;
+    ``[ladder_depth_hist..., nonconverged, ladder_draws_hist...]`` of
+    length ``DEPTH_BINS + 1 + DRAW_BINS``;
     baselines: ``[reprobes]``) -- owners are bit-identical either way.
 
     ``hier`` statics route the fused two-level kernel and emit the NODE
@@ -276,7 +277,7 @@ class RequestStreamDriver:
 
     def _register_metrics(self) -> None:
         """Claim this driver's slab windows (append-only; idempotent)."""
-        from repro.kernels.ref import DEPTH_BINS
+        from repro.kernels.ref import DEPTH_BINS, DRAW_BINS
 
         reg = self.metrics
         self._routed_name = reg.counter(
@@ -285,6 +286,7 @@ class RequestStreamDriver:
         reg.histogram("serve.served", self.n_bins)
         if self.algorithm == "asura":
             reg.histogram("asura.ladder_depth", DEPTH_BINS)
+            reg.histogram("asura.ladder_draws", DRAW_BINS)
             reg.counter("asura.nonconverged")
         else:
             reg.counter("baseline.reprobes")
@@ -312,6 +314,9 @@ class RequestStreamDriver:
             if self.algorithm == "asura":
                 delta = reg.add_hist(delta, "asura.ladder_depth", stats[:DEPTH_BINS])
                 delta = reg.add(delta, "asura.nonconverged", stats[DEPTH_BINS])
+                delta = reg.add_hist(
+                    delta, "asura.ladder_draws", stats[DEPTH_BINS + 1 :]
+                )
             else:
                 delta = reg.add(delta, "baseline.reprobes", stats[0])
         return delta

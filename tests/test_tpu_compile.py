@@ -20,6 +20,8 @@ every worker imports this file.
 from __future__ import annotations
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +121,38 @@ def test_place_replicas_fused_ref_compiles(tables):
         top_level=TOP, n_replicas=R, emit_nodes=True, **LADDER,
     ).compile()
     _assert_fits(compiled)
+
+
+def _gather_sizes(hlo: str) -> list[int]:
+    """Element counts of every ``gather`` result in an HLO module's text."""
+    sizes = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\][^=]*? gather\(", hlo):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        sizes.append(math.prod(dims))
+    return sizes
+
+
+def test_replica_ladder_compacts_its_stragglers(one_chip):
+    """One 65,536-lane serving batch on a 1,792-entry table (top level
+    10): the replica ladder runs a second draw loop over an 8,192-lane
+    straggler block, and the node ids come from the loop, not from a
+    gather over the 196,608 (lane, replica) pairs."""
+    from repro.kernels.ops import _place_replicas_fused_ref
+
+    s = functools.partial(_shape, one_chip)
+    table, top = 1_792, 10
+    compiled = _place_replicas_fused_ref.lower(
+        s((SERVE_BATCH,), jnp.uint32), s((table,), jnp.uint32),
+        s((table,), jnp.int32),
+        top_level=top, n_replicas=R, emit_nodes=True, **LADDER,
+    ).compile()
+    _assert_fits(compiled)
+    hlo = compiled.as_text()
+    assert R * SERVE_BATCH not in _gather_sizes(hlo)
+    loops = [line for line in hlo.splitlines() if " while(" in line]
+    block = SERVE_BATCH // 8
+    assert any(f"u32[{top + 1},{SERVE_BATCH}]" in line for line in loops)
+    assert any(f"u32[{top + 1},{block}]" in line for line in loops)
 
 
 def test_diff_replicas_fused_ref_compiles(tables):
