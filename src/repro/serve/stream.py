@@ -3,41 +3,39 @@
 The paper's headline claims (sub-microsecond calc time, <1% load
 variability) are about SERVING a placement function under real traffic.
 ``RequestStreamDriver`` is the batched, stateful driver that replaces
-per-call routing on the serving hot path:
+per-call routing on the serving hot path.  It is built from three parts:
 
-  * a device-resident request generator (``serve.traffic``): threefry
-    fold-in streams per GLOBAL lane, exact-u32 CDF sampling -- no host RNG
-    anywhere in the loop,
-  * a fused route+select pass: the batch routes through the replica
-    placement (ASURA's section-5.A kernel body, or the baselines' salted
-    fan-out), then a replica-selection policy picks one of the R holders
-    per request -- ``pow2`` is power-of-two-choices against the on-device
-    per-node load counters (arXiv 2312.10360: redundancy level + selection
-    policy jointly set the achievable balance),
-  * on-device load state: per-node served counters, a queue-depth
-    recurrence ``q' = max(q + arrivals - service, 0)`` and a queue-history
-    ring for p99 -- scatter-updated in the same jit,
+  * ONE counter-coupled tail (``_tail``): a replica-selection policy
+    picks one of the R holders per request -- ``pow2`` is
+    power-of-two-choices against the on-device per-node load counters
+    (arXiv 2312.10360: redundancy level + selection policy jointly set the
+    achievable balance) -- then the served histogram, the counters, a
+    queue-depth recurrence ``q' = max(q + arrivals - service, 0)``, a
+    queue-history ring for p99, the metrics slab delta and, on a mesh, the
+    batch's one exact integer psum,
+  * three bodies that trace it: ``step`` (one generated batch),
+    ``superstep`` (K generated batches drawn and routed jointly, the tail
+    scanned) and ``route_batch`` (one external, pow2-bucketed id batch);
+    ``serve_migrating`` dispatches the tail alone after its probe,
+  * three read rules that produce the holders: the flat replica placement
+    (``replica_owners_body``: ASURA's section-5.A ladder, the baselines'
+    salted fan-out, or the two-level node plane), a live migration
+    window's per-slot rule traced in the body (``migrate.live
+    .migrating_owners``: ``route_batch(ids, migration)``,
+    ``superstep_migrating``), and the window's cached fused probe
+    (``LiveMigration.route_replicas_device``: ``serve_migrating``).
 
-all inside ONE jit per step with zero host syncs (transfer-guard tested).
-Every selection in a batch reads the START-of-batch counters and the batch
-histogram merges once -- the standard batched approximation of
+Generation is a device-resident request generator (``serve.traffic``):
+threefry fold-in streams per GLOBAL lane, exact-u32 CDF sampling -- no host
+RNG anywhere in the loop, and zero host syncs per batch (transfer-guard
+tested).  Every selection in a batch reads the START-of-batch counters and
+the batch histogram merges once -- the standard batched approximation of
 least-loaded-of-two, and the property that makes the mesh path exact:
-
-``mesh=`` shards the request stream over ``launch/placement_mesh``'s 1-D
-``data`` mesh (the PR-6 follow-up): each shard generates ITS slice of the
-global lane range (bit-identical words by the counter-based construction),
-routes and selects against the replicated kilobyte tables and replicated
-start-of-batch counters, and the per-node load histogram merges with ONE
-exact integer psum per batch -- so the sharded stream is bit-identical to
-the single-device stream (selftest-enforced at 8 forced host devices).
-
-External id batches (``route_batch``) reuse the migration planner's pow2
-bucketing so ragged tails share one compile per bucket.  Given a live
-migration, ``route_batch`` routes host-fed batches through the window's
-per-slot read rule in one jit (``route_migrating``), and
-``serve_migrating`` drives the generated stream through it via the cached
-fused ``route_replicas_device`` probe -- dual-version serving keeps
-working under the batched driver.
+``mesh=`` shards the generated stream over ``launch/placement_mesh``'s 1-D
+``data`` mesh, each shard generating and routing ITS slice of the global
+lane range against replicated tables and start-of-batch counters, so the
+sharded stream is bit-identical to the single-device stream
+(selftest-enforced at 8 forced host devices).
 """
 
 from __future__ import annotations
@@ -190,10 +188,11 @@ class RequestStreamDriver:
       * ``qhist``  -- (max_hist, n_bins) int32 queue-depth ring (p99),
       * ``_step``  -- int32 device scalar (the fold-in stream position).
 
-    ``step()`` runs one fused generate+route+select+count batch and
-    returns the chosen nodes (device array; shard-partitioned on a mesh).
-    ``step_traces`` counts jit traces of the fused step -- the tripwire
-    that repeated steps stop retracing.
+    Every serving call runs ONE jitted program per host dispatch and
+    returns the chosen nodes (device arrays; shard-partitioned on a mesh).
+    ``step_traces`` counts jit traces of the one-batch programs (``step``,
+    ``route_batch``) and ``superstep_traces`` those of the scan-fused ones
+    -- the tripwires that repeated calls stop retracing.
     """
 
     def __init__(
@@ -339,276 +338,204 @@ class RequestStreamDriver:
             fn = self._fns[key] = build()
         return fn
 
-    # -- the fused step -------------------------------------------------------
+    def _fixed_operands(self):
+        return (self._service, self.traffic.thresholds_dev)
 
-    def _batch_body(self, statics: tuple):
-        """The traced ONE-BATCH body ``step()`` and ``superstep()`` share:
-        generate -> route -> select -> count, signature
+    # -- the counter-coupled tail (traced once per batch by every body) -------
 
-            body(key, step_idx, counts, queue, qhist, *rest)
-              -> (counts, queue, qhist, [slab,] step_idx + 1, chosen)
+    def _split(self, rest):
+        """A body's trailing arguments -> ``([slab], operands)``."""
+        return (rest[:1], rest[1:]) if self._instrumented else ((), rest)
 
-        where ``rest = [slab,] service, thresholds, *tables``.  Both
-        drivers trace EXACTLY this function (step jits it directly, the
-        superstep scans it), which is what makes ``superstep(k)``
-        bit-identical to K sequential ``step()`` calls by construction.
+    def _tail(self, owners, sel, carry, service, *, n_routed, valid=None, stats=None):
+        """select -> served histogram -> slab delta -> [mesh psum] ->
+        counters, queue recurrence, queue-history ring.
 
-        With a live ``MetricsRegistry`` the body also threads the u32
-        metrics slab: routed/served/kernel-stats accumulate into a zeros
-        DELTA slab in-register, and under a mesh the delta rides the
-        batch's single exact integer psum alongside the per-node histogram
-        (DESIGN.md section 13) -- still zero host syncs per batch.
+        ``carry`` is ``(counts, queue, qhist, [slab,] step_idx)``; returns
+        ``(carry', chosen)`` with the stream position advanced.  ``valid``
+        masks pad lanes out of every count; ``n_routed`` and the routing
+        kernel's ``stats`` feed the slab (instrumented only).  Under a mesh
+        the histogram and the slab delta merge with ONE exact integer
+        psum, which keeps the sharded stream bit-identical to one device.
         """
         import jax
         import jax.numpy as jnp
 
-        batch, R = self.batch, self.n_replicas
-        policy, n_bins, max_hist = self.policy, self.n_bins, self.max_hist
-        id_salt = self.traffic.id_salt
-        instrumented = self._instrumented
-        owners_fn = replica_owners_body(statics, R, emit_stats=instrumented)
-        sweep = self._sweep
-        driver = self
+        n_bins = self.n_bins
+        counts, queue, qhist, *slab, step_idx = carry
+        chosen = select_replica(
+            owners, sel, counts, policy=self.policy, n_replicas=self.n_replicas
+        )
+        hist = jnp.zeros((n_bins,), jnp.int32).at[chosen].add(
+            1 if valid is None else valid.astype(jnp.int32)
+        )
+        if slab:
+            delta = self.metrics.add(jnp.zeros_like(slab[0]), self._routed_name, n_routed)
+            delta = self._accumulate(delta, hist, stats)
+        if self._sweep is not None:
+            from repro.launch.placement_mesh import DATA_AXIS
 
-        def body(key, step_idx, counts, queue, qhist, *rest):
-            if instrumented:
-                slab, service, thresholds, *tables = rest
-            else:
-                service, thresholds, *tables = rest
-            if sweep is None:
-                lanes = jnp.arange(batch, dtype=jnp.uint32)
-            else:
-                from repro.launch.placement_mesh import DATA_AXIS
-
-                local = batch // sweep.n_devices
-                first = jax.lax.axis_index(DATA_AXIS).astype(jnp.uint32) * local
-                lanes = first + jnp.arange(local, dtype=jnp.uint32)
-            ids, sel = TrafficModel.draw(key, step_idx, lanes, thresholds, id_salt)
-            if instrumented:
-                owners, stats = owners_fn(ids, *tables)
-            else:
-                owners = owners_fn(ids, *tables)
-            chosen = select_replica(
-                owners, sel, counts, policy=policy, n_replicas=R
-            )
-            hist = jnp.zeros((n_bins,), jnp.int32).at[chosen].add(1)
-            if instrumented:
-                delta = jnp.zeros_like(slab)
-                delta = driver.metrics.add(
-                    delta, driver._routed_name, lanes.shape[0]
+            if slab:
+                merged = jax.lax.psum(
+                    jnp.concatenate([hist, delta.astype(jnp.int32)]), DATA_AXIS
                 )
-                delta = driver._accumulate(delta, hist, stats)
-            if sweep is not None:
-                from repro.launch.placement_mesh import DATA_AXIS
+                hist, delta = merged[:n_bins], merged[n_bins:].astype(jnp.uint32)
+            else:
+                hist = jax.lax.psum(hist, DATA_AXIS)
+        counts = counts + hist
+        queue = jnp.maximum(queue + hist - service, 0)
+        qhist = jax.lax.dynamic_update_slice(
+            qhist, queue[None], (step_idx % self.max_hist, jnp.int32(0))
+        )
+        if slab:
+            slab = [slab[0] + delta]
+        return (counts, queue, qhist, *slab, step_idx + 1), chosen
 
-                if instrumented:
-                    # the slab delta rides the step's ONE exact psum
-                    merged = jax.lax.psum(
-                        jnp.concatenate([hist, delta.astype(jnp.int32)]),
-                        DATA_AXIS,
-                    )
-                    hist = merged[:n_bins]
-                    delta = merged[n_bins:].astype(jnp.uint32)
-                else:
-                    hist = jax.lax.psum(hist, DATA_AXIS)
-            counts = counts + hist
-            queue = jnp.maximum(queue + hist - service, 0)
-            qhist = jax.lax.dynamic_update_slice(
-                qhist, queue[None], (step_idx % max_hist, jnp.int32(0))
-            )
-            if instrumented:
-                return counts, queue, qhist, slab + delta, step_idx + 1, chosen
-            return counts, queue, qhist, step_idx + 1, chosen
+    def _dispatch(self, fn, lead, operands, n_steps: int = 1) -> list:
+        """Call a serving jit ``fn(*lead, counts, queue, qhist, [slab,]
+        *operands) -> (counts, queue, qhist, [slab,] step_idx, *outs)``,
+        keep the new state and return ``outs``."""
+        slab = [self.metrics.slab()] if self._instrumented else []
+        out = fn(*lead, self.counts, self.queue, self.qhist, *slab, *operands)
+        self.counts, self.queue, self.qhist = out[:3]
+        if slab:
+            self.metrics.set_slab(out[3])
+        self._step, *outs = out[3 + len(slab) :]
+        self.steps_done += n_steps
+        return outs
 
-        return body
+    # -- the generated stream: one batch, or K scan-fused ---------------------
 
-    def _spec_counts(self, statics: tuple) -> tuple[int, int]:
-        """(n_in, n_rep_out) for the mesh shard_map wrap of a batch body."""
-        # flat routing carries 2 table operands; the two-level path carries
-        # the 8-array stacked hierarchy artifact (kernels/hierarchy.py)
-        n_tables = (8 if statics[0] == "hier" else 2) + len(self._fixed_operands())
-        n_in = (6 if self._instrumented else 5) + n_tables
-        n_rep_out = 4 if self._instrumented else 3
-        return n_in, n_rep_out
+    def _lanes(self):
+        """This shard's GLOBAL lane indices (traced; all of them off a mesh)."""
+        import jax
+        import jax.numpy as jnp
 
-    def _step_fn(self, statics: tuple):
-        """One-jit batch step: the shared batch body, jitted (shard_mapped
-        on a mesh), plus the per-TRACE retrace tripwire."""
+        if self._sweep is None:
+            return jnp.arange(self.batch, dtype=jnp.uint32)
+        from repro.launch.placement_mesh import DATA_AXIS
+
+        local = self.batch // self._sweep.n_devices
+        first = jax.lax.axis_index(DATA_AXIS).astype(jnp.uint32) * local
+        return first + jnp.arange(local, dtype=jnp.uint32)
+
+    def _jit(self, body, lead_axes: int = 0):
+        """jit a generated-stream body; on a mesh, shard_map it first.
+        Every operand is replicated (lanes derive from ``axis_index``, so
+        there is no partitioned INPUT at all); only ``chosen`` comes back
+        lane-partitioned, behind ``lead_axes`` replicated axes."""
         import jax
 
-        body = self._batch_body(statics)
-        sweep = self._sweep
-        driver = self
-
-        def stepped(*args):
-            driver.ledger.incr("serve.step_traces")  # fires per TRACE only
-            return body(*args)
-
-        if sweep is None:
-            return jax.jit(stepped)
+        if self._sweep is None:
+            return jax.jit(body)
         from jax.sharding import PartitionSpec as P
 
         from repro.launch.placement_mesh import DATA_AXIS
 
-        n_in, n_rep_out = self._spec_counts(statics)
+        n_carry = 5 if self._instrumented else 4
         return jax.jit(
             jax.shard_map(
-                stepped,
-                mesh=sweep.mesh,
-                # everything replicated: lanes derive from axis_index, so
-                # there is no partitioned INPUT at all -- only the chosen
-                # lanes come back shard-partitioned.
-                in_specs=(P(),) * n_in,
-                out_specs=(P(),) * (n_rep_out + 1) + (P(DATA_AXIS),),
+                body,
+                mesh=self._sweep.mesh,
+                in_specs=P(),
+                out_specs=(P(),) * n_carry + (P(*(None,) * lead_axes, DATA_AXIS),),
                 check_vma=False,  # while_loop ladders have no replication rule
             )
         )
 
-    def _superstep_fn(self, statics: tuple, k: int):
+    def _step_fn(self, statics: tuple):
+        """ONE batch in one jit: generate -> route -> the tail, signature
+
+            stepped(key, step_idx, counts, queue, qhist, [slab,] service,
+                    thresholds, *tables)
+              -> (counts, queue, qhist, [slab,] step_idx + 1, chosen)
+
+        With a live ``MetricsRegistry`` the body also threads the u32
+        metrics slab (DESIGN.md section 13) -- still zero host syncs."""
+        instrumented, id_salt = self._instrumented, self.traffic.id_salt
+        owners_fn = replica_owners_body(statics, self.n_replicas, emit_stats=instrumented)
+        driver = self
+
+        def stepped(key, step_idx, counts, queue, qhist, *rest):
+            driver.ledger.incr("serve.step_traces")  # fires per TRACE only
+            slab, (service, thresholds, *tables) = driver._split(rest)
+            lanes = driver._lanes()
+            ids, sel = TrafficModel.draw(key, step_idx, lanes, thresholds, id_salt)
+            routed = owners_fn(ids, *tables)
+            owners, stats = routed if instrumented else (routed, None)
+            carry, chosen = driver._tail(
+                owners, sel, (counts, queue, qhist, *slab, step_idx), service,
+                n_routed=lanes.shape[0], stats=stats,
+            )
+            return (*carry, chosen)
+
+        return self._jit(stepped)
+
+    def _superstep_fn(self, statics: tuple, k: int, migrating: bool = False):
         """K fused batches in ONE jit, restructured around what actually
         needs to be sequential:
 
           1. generate ALL K sub-batches in one vectorized draw (every
              threefry word is a pure function of (key, step, lane)),
-          2. route the joint (k*batch,) id block through ONE ladder
-             while_loop -- amortizing the loop's per-iteration dispatch
-             overhead k-fold instead of paying it per sub-batch,
-          3. ``lax.scan`` only the counter-COUPLED tail (pow2 select,
-             count, queue ring) with (counts, queue, qhist, [slab,]
-             step_idx) as the carry.
+          2. route the joint (k*batch,) id block through ONE owners call
+             -- one ladder while_loop instead of K,
+          3. ``lax.scan`` only the counter-coupled tail with
+             (counts, queue, qhist, [slab,] step_idx) as the carry.
 
-        This is still bit-identical to K sequential ``step()`` calls:
-        generation is counter-based (stateless), the routing loops are
-        per-lane pure (a lane's result and its emitted stats never depend
-        on which other lanes share the batch -- the same partition
-        invariance the sharded stream's psum merge already relies on,
-        selftest-enforced), and the selection scan reads counters fresh
-        as of the previous sub-batch exactly as ``step()`` does.  The
-        once-per-batch slab contributions (routed counter, kernel stats)
-        fold in once per SUPERSTEP with the same u32 modular sum.  On a
-        mesh the per-sub-batch exact psum stays INSIDE the scan (K+1
-        psums fused into one dispatch), so sharded supersteps remain
-        bit-identical to single-device.  ``chosen`` comes back stacked
-        (k, batch).
-        """
+        Bit-identical to K sequential one-batch calls: generation is
+        counter-based, the owners functions are per-lane pure (a lane's
+        holders and emitted stats never depend on which other lanes share
+        the batch -- the partition invariance the sharded stream's psum
+        merge relies on), and the scan reads counters fresh as of the
+        previous sub-batch.  The joint route's kernel stats fold into the
+        first sub-batch's slab delta (u32 sums: the same total).  On a mesh
+        each sub-batch's exact psum stays INSIDE the scan.  ``chosen``
+        comes back stacked (k, batch).
+
+        ``migrating`` swaps the flat placement for the live window's read
+        rule (``migrate.live.migrating_owners(statics)``, tables = the
+        window's operands): it emits no stats, and the body also returns
+        the drawn ids -- ``superstep_migrating``."""
         import jax
         import jax.numpy as jnp
 
-        batch, R = self.batch, self.n_replicas
-        policy, n_bins, max_hist = self.policy, self.n_bins, self.max_hist
-        id_salt = self.traffic.id_salt
-        instrumented = self._instrumented
-        owners_fn = replica_owners_body(statics, R, emit_stats=instrumented)
-        sweep = self._sweep
+        R, id_salt = self.n_replicas, self.traffic.id_salt
+        emit_stats = self._instrumented and not migrating
+        if migrating:
+            from repro.migrate.live import migrating_owners
+
+            owners_fn = migrating_owners(statics)
+        else:
+            owners_fn = replica_owners_body(statics, R, emit_stats=emit_stats)
         driver = self
 
         def super_body(key, step_idx, counts, queue, qhist, *rest):
             driver.ledger.incr("serve.superstep_traces")  # per TRACE only
-            if instrumented:
-                slab, service, thresholds, *tables = rest
-            else:
-                service, thresholds, *tables = rest
-            if sweep is None:
-                local = batch
-                lanes = jnp.arange(batch, dtype=jnp.uint32)
-            else:
-                from repro.launch.placement_mesh import DATA_AXIS
-
-                local = batch // sweep.n_devices
-                first = jax.lax.axis_index(DATA_AXIS).astype(jnp.uint32) * local
-                lanes = first + jnp.arange(local, dtype=jnp.uint32)
-
-            # stage 1+2: all K sub-batches drawn and routed jointly
+            slab, (service, thresholds, *tables) = driver._split(rest)
+            lanes = driver._lanes()
+            local = lanes.shape[0]
             steps = step_idx + jnp.arange(k, dtype=step_idx.dtype)
             ids, sel = jax.vmap(
                 lambda s: TrafficModel.draw(key, s, lanes, thresholds, id_salt)
             )(steps)  # (k, local) each
-            if instrumented:
-                owners, stats = owners_fn(ids.reshape(k * local), *tables)
-            else:
-                owners = owners_fn(ids.reshape(k * local), *tables)
-            owners = owners.reshape(k, local, R)
+            routed = owners_fn(ids.reshape(k * local), *tables)
+            owners, stats = routed if emit_stats else (routed, None)
+            if stats is not None:
+                stats = jnp.zeros((k, *stats.shape), stats.dtype).at[0].set(stats)
 
-            # stage 3: the counter-coupled tail, scanned
             def sub(carry, xs):
-                if instrumented:
-                    counts, queue, qhist, slab, si = carry
-                else:
-                    counts, queue, qhist, si = carry
-                owners_i, sel_i = xs
-                chosen = select_replica(
-                    owners_i, sel_i, counts, policy=policy, n_replicas=R
+                owners_i, sel_i, stats_i = xs
+                return driver._tail(
+                    owners_i, sel_i, carry, service, n_routed=local, stats=stats_i
                 )
-                hist = jnp.zeros((n_bins,), jnp.int32).at[chosen].add(1)
-                if instrumented:
-                    delta = jnp.zeros_like(slab)
-                    delta = driver._accumulate(delta, hist, None)
-                if sweep is not None:
-                    from repro.launch.placement_mesh import DATA_AXIS
 
-                    if instrumented:
-                        merged = jax.lax.psum(
-                            jnp.concatenate([hist, delta.astype(jnp.int32)]),
-                            DATA_AXIS,
-                        )
-                        hist = merged[:n_bins]
-                        delta = merged[n_bins:].astype(jnp.uint32)
-                    else:
-                        hist = jax.lax.psum(hist, DATA_AXIS)
-                counts = counts + hist
-                queue = jnp.maximum(queue + hist - service, 0)
-                qhist = jax.lax.dynamic_update_slice(
-                    qhist, queue[None], (si % max_hist, jnp.int32(0))
-                )
-                if instrumented:
-                    return (counts, queue, qhist, slab + delta, si + 1), chosen
-                return (counts, queue, qhist, si + 1), chosen
-
-            if instrumented:
-                carry0 = (counts, queue, qhist, slab, step_idx)
-            else:
-                carry0 = (counts, queue, qhist, step_idx)
-            carry, chosen = jax.lax.scan(sub, carry0, (owners, sel), length=k)
-            if instrumented:
-                # once-per-superstep slab contributions: the routed counter
-                # and the joint route's kernel stats (their per-sub-batch
-                # sums are the same u32 total -- partition invariance)
-                counts, queue, qhist, slab, si = carry
-                delta = jnp.zeros_like(slab)
-                delta = driver.metrics.add(
-                    delta, driver._routed_name, k * local
-                )
-                delta = driver._accumulate(delta, jnp.zeros((n_bins,), jnp.int32), stats)
-                if sweep is not None:
-                    from repro.launch.placement_mesh import DATA_AXIS
-
-                    delta = jax.lax.psum(
-                        delta.astype(jnp.int32), DATA_AXIS
-                    ).astype(jnp.uint32)
-                carry = (counts, queue, qhist, slab + delta, si)
-            return (*carry, chosen)
-
-        if sweep is None:
-            return jax.jit(super_body)
-        from jax.sharding import PartitionSpec as P
-
-        from repro.launch.placement_mesh import DATA_AXIS
-
-        n_in, n_rep_out = self._spec_counts(statics)
-        return jax.jit(
-            jax.shard_map(
-                super_body,
-                mesh=sweep.mesh,
-                in_specs=(P(),) * n_in,
-                # stacked chosen is (k, local): partitioned on the LANE
-                # axis, replicated over the scan axis.
-                out_specs=(P(),) * (n_rep_out + 1) + (P(None, DATA_AXIS),),
-                check_vma=False,
+            carry, chosen = jax.lax.scan(
+                sub, (counts, queue, qhist, *slab, step_idx),
+                (owners.reshape(k, local, R), sel, stats), length=k,
             )
-        )
+            return (*carry, *([ids] if migrating else []), chosen)
 
-    def _fixed_operands(self):
-        return (self._service, self.traffic.thresholds_dev)
+        return self._jit(super_body, lead_axes=1)
 
     def step(self):
         """Serve one generated batch -> (batch,) int32 chosen nodes (device
@@ -617,19 +544,9 @@ class RequestStreamDriver:
         scalar."""
         tables, statics = route_statics(self.engine, self.algorithm)
         fn = self._cached(("step", statics), lambda: self._step_fn(statics))
-        if self._instrumented:
-            (self.counts, self.queue, self.qhist, slab, self._step,
-             chosen) = fn(
-                self._key, self._step, self.counts, self.queue, self.qhist,
-                self.metrics.slab(), *self._fixed_operands(), *tables,
-            )
-            self.metrics.set_slab(slab)
-        else:
-            self.counts, self.queue, self.qhist, self._step, chosen = fn(
-                self._key, self._step, self.counts, self.queue, self.qhist,
-                *self._fixed_operands(), *tables,
-            )
-        self.steps_done += 1
+        (chosen,) = self._dispatch(
+            fn, (self._key, self._step), (*self._fixed_operands(), *tables)
+        )
         return chosen
 
     def superstep(self, k: int):
@@ -638,35 +555,32 @@ class RequestStreamDriver:
         when sharded).
 
         Bit-identical to K sequential ``step()`` calls -- same counters,
-        queue ring, metrics slab and chosen nodes: generation and routing
-        are per-lane pure, so the superstep draws and routes all K
-        sub-batches JOINTLY (one ladder while_loop instead of K) and scans
-        only the counter-coupled select/count tail (``_superstep_fn``).
+        queue ring, metrics slab and chosen nodes: the superstep draws and
+        routes all K sub-batches JOINTLY (one ladder while_loop instead of
+        K) and scans only the counter-coupled tail (``_superstep_fn``).
         Amortizes both the host dispatch and the routing loop's
         per-iteration overhead ~k-fold; at most one slab transfer per
         superstep when instrumented.  Pick k so ``k * batch`` trails the
         metric-read cadence (README "Throughput tuning")."""
+        (chosen,) = self._run_superstep(k)
+        return chosen
+
+    def _run_superstep(self, k, migration=None) -> list:
         k = int(k)
         if k < 1:
             raise ValueError(f"superstep needs k >= 1, got {k}")
-        tables, statics = route_statics(self.engine, self.algorithm)
-        fn = self._cached(
-            ("superstep", statics, k), lambda: self._superstep_fn(statics, k)
-        )
-        if self._instrumented:
-            (self.counts, self.queue, self.qhist, slab, self._step,
-             chosen) = fn(
-                self._key, self._step, self.counts, self.queue, self.qhist,
-                self.metrics.slab(), *self._fixed_operands(), *tables,
-            )
-            self.metrics.set_slab(slab)
+        if migration is None:
+            tables, statics = route_statics(self.engine, self.algorithm)
         else:
-            self.counts, self.queue, self.qhist, self._step, chosen = fn(
-                self._key, self._step, self.counts, self.queue, self.qhist,
-                *self._fixed_operands(), *tables,
-            )
-        self.steps_done += k
-        return chosen
+            statics, tables = migration.route_operands()
+        migrating = migration is not None
+        fn = self._cached(
+            ("superstep", migrating, statics, k),
+            lambda: self._superstep_fn(statics, k, migrating),
+        )
+        return self._dispatch(
+            fn, (self._key, self._step), (*self._fixed_operands(), *tables), n_steps=k
+        )
 
     # -- external batches (pow2 bucketing -- ragged tails share compiles) -----
 
@@ -680,38 +594,20 @@ class RequestStreamDriver:
         import jax
         import jax.numpy as jnp
 
-        R, policy = self.n_replicas, self.policy
-        n_bins, max_hist = self.n_bins, self.max_hist
-        instrumented = self._instrumented
         driver = self
 
         def body(ids, n_valid, key, step_idx, counts, queue, qhist, *rest):
             driver.ledger.incr("serve.step_traces")
-            if instrumented:
-                slab, service, *tables = rest
-            else:
-                service, *tables = rest
+            slab, (service, *tables) = driver._split(rest)
             lanes = jnp.arange(ids.shape[0], dtype=jnp.uint32)
             valid = lanes < n_valid.astype(jnp.uint32)
             sel = TrafficModel.lane_words(key, step_idx, lanes, 1)[:, 0]
             owners = owners_fn(ids.astype(jnp.uint32), *tables)
-            chosen = select_replica(
-                owners, sel, counts, policy=policy, n_replicas=R
+            carry, chosen = driver._tail(
+                owners, sel, (counts, queue, qhist, *slab, step_idx), service,
+                n_routed=n_valid, valid=valid,  # pad lanes never count
             )
-            hist = jnp.zeros((n_bins,), jnp.int32).at[chosen].add(
-                valid.astype(jnp.int32)  # pad lanes never count
-            )
-            counts = counts + hist
-            queue = jnp.maximum(queue + hist - service, 0)
-            qhist = jax.lax.dynamic_update_slice(
-                qhist, queue[None], (step_idx % max_hist, jnp.int32(0))
-            )
-            if instrumented:
-                delta = jnp.zeros_like(slab)
-                delta = driver.metrics.add(delta, driver._routed_name, n_valid)
-                delta = driver._accumulate(delta, hist, None)
-                return counts, queue, qhist, slab + delta, step_idx + 1, chosen
-            return counts, queue, qhist, step_idx + 1, chosen
+            return (*carry, chosen)
 
         body.__name__ = body.__qualname__ = name
         return jax.jit(body)
@@ -765,20 +661,10 @@ class RequestStreamDriver:
                     ("route_batch", statics),
                     lambda: self._route_batch_fn(replica_owners_body(statics, self.n_replicas)),
                 )
-            if self._instrumented:
-                (self.counts, self.queue, self.qhist, slab, self._step,
-                 chosen) = fn(
-                    padded, jnp.uint32(n_valid), self._key, self._step,
-                    self.counts, self.queue, self.qhist, self.metrics.slab(),
-                    self._service, *tables,
-                )
-                self.metrics.set_slab(slab)
-            else:
-                self.counts, self.queue, self.qhist, self._step, chosen = fn(
-                    padded, jnp.uint32(n_valid), self._key, self._step,
-                    self.counts, self.queue, self.qhist, self._service, *tables,
-                )
-            self.steps_done += 1
+            (chosen,) = self._dispatch(
+                fn, (padded, jnp.uint32(n_valid), self._key, self._step),
+                (self._service, *tables),
+            )
             return _head(chosen, n)
 
     def _check_window(self, migration) -> None:
@@ -809,38 +695,21 @@ class RequestStreamDriver:
 
         return gen
 
-    def _mig_select_fn(self):
+    def _tail_fn(self):
+        """The tail alone, jitted: what ``serve_migrating`` dispatches
+        after the window's probe has routed the batch."""
         import jax
-        import jax.numpy as jnp
 
-        policy, R = self.policy, self.n_replicas
-        n_bins, max_hist = self.n_bins, self.max_hist
-        instrumented = self._instrumented
         driver = self
 
         @jax.jit
         def select(owners, sel, step_idx, counts, queue, qhist, *rest):
-            if instrumented:
-                slab, service = rest
-            else:
-                (service,) = rest
-            chosen = select_replica(
-                owners, sel, counts, policy=policy, n_replicas=R
+            slab, (service,) = driver._split(rest)
+            carry, chosen = driver._tail(
+                owners, sel, (counts, queue, qhist, *slab, step_idx), service,
+                n_routed=owners.shape[0],
             )
-            hist = jnp.zeros((n_bins,), jnp.int32).at[chosen].add(1)
-            counts = counts + hist
-            queue = jnp.maximum(queue + hist - service, 0)
-            qhist = jax.lax.dynamic_update_slice(
-                qhist, queue[None], (step_idx % max_hist, jnp.int32(0))
-            )
-            if instrumented:
-                delta = jnp.zeros_like(slab)
-                delta = driver.metrics.add(
-                    delta, driver._routed_name, owners.shape[0]
-                )
-                delta = driver._accumulate(delta, hist, None)
-                return counts, queue, qhist, slab + delta, step_idx + 1, chosen
-            return counts, queue, qhist, step_idx + 1, chosen
+            return (*carry, chosen)
 
         return select
 
@@ -851,121 +720,28 @@ class RequestStreamDriver:
         Routing goes through the window's dual-version replica read rule
         (``LiveMigration.route_replicas_device`` -- the cached fused
         probe), so every request lands on a node that physically holds its
-        datum mid-drain.  Three jitted dispatches (generate, route,
-        select+count), zero host syncs after the per-round pending-view
-        refresh.  Single-device, like the window itself."""
+        datum mid-drain.  Three jitted dispatches (generate, route, the
+        tail), zero host syncs after the per-round pending-view refresh.
+        Single-device, like the window itself."""
         self._check_window(migration)
         gen = self._cached(("gen",), self._gen_fn)
         ids, sel = gen(self._key, self._step, self.traffic.thresholds_dev)
         owners = migration.route_replicas_device(ids)
-        select = self._cached(("mig_select",), self._mig_select_fn)
-        if self._instrumented:
-            (self.counts, self.queue, self.qhist, slab, self._step,
-             chosen) = select(
-                owners, sel, self._step, self.counts, self.queue, self.qhist,
-                self.metrics.slab(), self._service,
-            )
-            self.metrics.set_slab(slab)
-        else:
-            self.counts, self.queue, self.qhist, self._step, chosen = select(
-                owners, sel, self._step, self.counts, self.queue, self.qhist,
-                self._service,
-            )
-        self.steps_done += 1
+        tail = self._cached(("tail",), self._tail_fn)
+        (chosen,) = self._dispatch(tail, (owners, sel, self._step), (self._service,))
         return ids, chosen
-
-    def _mig_superstep_fn(self, statics: tuple, k: int):
-        """K migration-window batches in ONE jit: generate, the fused
-        dual-version replica read rule (the ``migrate.live``
-        ``migrating_owners`` body) and select+count, scanned with the
-        serving state as the carry -- the superstep twin of
-        ``serve_migrating``'s three dispatches."""
-        import jax
-        import jax.numpy as jnp
-
-        from repro.migrate.live import migrating_owners
-
-        R = statics[3]
-        batch, id_salt = self.batch, self.traffic.id_salt
-        policy, n_bins, max_hist = self.policy, self.n_bins, self.max_hist
-        instrumented = self._instrumented
-        owners_fn = migrating_owners(statics)
-        driver = self
-
-        @jax.jit
-        def super_body(key, step_idx, counts, queue, qhist, *rest):
-            driver.ledger.incr("serve.superstep_traces")  # per TRACE only
-            if instrumented:
-                slab, service, thresholds, *tables = rest
-                carry0 = (counts, queue, qhist, slab, step_idx)
-            else:
-                service, thresholds, *tables = rest
-                carry0 = (counts, queue, qhist, step_idx)
-
-            def sub(carry, _):
-                if instrumented:
-                    c, q, qh, sl, si = carry
-                else:
-                    c, q, qh, si = carry
-                lanes = jnp.arange(batch, dtype=jnp.uint32)
-                ids, sel = TrafficModel.draw(key, si, lanes, thresholds, id_salt)
-                owners = owners_fn(ids.astype(jnp.uint32), *tables)
-                chosen = select_replica(
-                    owners, sel, c, policy=policy, n_replicas=R
-                )
-                hist = jnp.zeros((n_bins,), jnp.int32).at[chosen].add(1)
-                c = c + hist
-                q = jnp.maximum(q + hist - service, 0)
-                qh = jax.lax.dynamic_update_slice(
-                    qh, q[None], (si % max_hist, jnp.int32(0))
-                )
-                if instrumented:
-                    delta = jnp.zeros_like(sl)
-                    delta = driver.metrics.add(
-                        delta, driver._routed_name, owners.shape[0]
-                    )
-                    delta = driver._accumulate(delta, hist, None)
-                    return (c, q, qh, sl + delta, si + 1), (ids, chosen)
-                return (c, q, qh, si + 1), (ids, chosen)
-
-            carry, (ids, chosen) = jax.lax.scan(sub, carry0, None, length=k)
-            return (*carry, ids, chosen)
-
-        return super_body
 
     def superstep_migrating(self, migration, k: int):
         """Serve K generated batches THROUGH a live migration window in
         ONE host dispatch -> (datum_ids, chosen), each (k, batch).
 
-        Bit-identical to K sequential ``serve_migrating`` calls against
-        the same pending view: the whole dual-version read rule runs
-        inside the scan, counters stay fresh between sub-batches, and the
+        The superstep body of ``superstep`` with the window's read rule as
+        its owners function: bit-identical to K sequential
+        ``serve_migrating`` calls against the same pending view -- the
         pending snapshot is the one at call time (refresh per round, as
         with ``serve_migrating``).  Single-device, like the window."""
         self._check_window(migration)
-        k = int(k)
-        if k < 1:
-            raise ValueError(f"superstep needs k >= 1, got {k}")
-        statics, tables = migration.route_operands()
-        fn = self._cached(
-            ("mig_superstep", statics, k),
-            lambda: self._mig_superstep_fn(statics, k),
-        )
-        operands = (self._service, self.traffic.thresholds_dev, *tables)
-        if self._instrumented:
-            (self.counts, self.queue, self.qhist, slab, self._step,
-             ids, chosen) = fn(
-                self._key, self._step, self.counts, self.queue, self.qhist,
-                self.metrics.slab(), *operands,
-            )
-            self.metrics.set_slab(slab)
-        else:
-            (self.counts, self.queue, self.qhist, self._step,
-             ids, chosen) = fn(
-                self._key, self._step, self.counts, self.queue, self.qhist,
-                *operands,
-            )
-        self.steps_done += k
+        ids, chosen = self._run_superstep(k, migration)
         return ids, chosen
 
     # -- host-facing metrics (each accessor is ONE deliberate sync) -----------

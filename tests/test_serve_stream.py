@@ -296,3 +296,56 @@ def test_stream_driver_factory_binds_router_algorithm():
     chosen = np.asarray(d.step())
     assert chosen.shape == (512,)
     assert d.load_counts().sum() == 512
+
+
+# ---------------------------------------------------------------------------
+# The shared tail: every serving call, instrumented vs plain
+# ---------------------------------------------------------------------------
+
+_KEYS = np.random.default_rng(9).integers(0, 1 << 14, 700, dtype=np.uint32)
+
+# Each call serves two batches and returns the chosen nodes, flattened.
+_SERVING_CALLS = {
+    "step": lambda d, m: [d.step() for _ in range(2)],
+    "superstep": lambda d, m: [d.superstep(2)],
+    "route_batch": lambda d, m: [d.route_batch(_KEYS), d.route_batch(_KEYS[:300])],
+    "route_batch_migrating": lambda d, m: [
+        d.route_batch(_KEYS, migration=m), d.route_batch(_KEYS[:300], migration=m)
+    ],
+    "serve_migrating": lambda d, m: [d.serve_migrating(m)[1] for _ in range(2)],
+    "superstep_migrating": lambda d, m: [d.superstep_migrating(m, 2)[1]],
+}
+
+
+@pytest.mark.parametrize("call", sorted(_SERVING_CALLS))
+def test_instrumented_serving_matches_plain_and_counts_what_it_routes(call):
+    """Every serving entry point runs the same tail with and without the
+    metrics slab: an instrumented driver and its plain twin choose the
+    same nodes and keep the same counters, queue and queue ring, and the
+    counters, the slab's served histogram and its routed counter count
+    the valid lanes only (a ragged external batch counts no pad)."""
+    from repro.obs import MetricsRegistry
+
+    router = Router({i: 1.0 for i in range(8)})
+    mig = router.begin_scale_migration(
+        np.arange(20_000, dtype=np.uint32), add=(8, 1.0), n_replicas=3,
+        egress={n: 60 for n in range(9)},
+    )
+    mig.round()
+    reg = MetricsRegistry()
+    kw = dict(batch=512, n_keys=1 << 12, n_replicas=3, policy="pow2", seed=5, n_bins=9)
+    plain, metered = router.stream_driver(**kw), router.stream_driver(metrics=reg, **kw)
+    chosen = [
+        np.concatenate([np.asarray(c).ravel() for c in _SERVING_CALLS[call](d, mig)])
+        for d in (plain, metered)
+    ]
+    assert not mig.done, "window drained; the migrating calls would serve flat"
+    assert np.array_equal(chosen[0], chosen[1])
+    for attr in ("counts", "queue", "qhist"):
+        assert np.array_equal(np.asarray(getattr(plain, attr)),
+                              np.asarray(getattr(metered, attr))), attr
+    assert np.array_equal(np.asarray(metered.counts), np.bincount(chosen[1], minlength=9))
+    snap = reg.snapshot()
+    assert np.array_equal(snap["serve.served"], np.asarray(metered.counts))
+    assert int(snap[f"serve.routed.{metered.algorithm}.pow2"]) == chosen[1].size
+    assert chosen[1].size == (1000 if call.startswith("route_batch") else 1024)
